@@ -72,7 +72,7 @@ def run():
     for shard_m, a_dim, b_dim in SHAPES:
         m = shard_m * len(devs)
         x, y = rand(0, (m, a_dim)), rand(1, (m, b_dim))
-        with mesh:
+        with jax.set_mesh(mesh):
             us_p, _ = timeit_arm(
                 _mmt, x, y, policy=psum_pol, expect_executors=EXPECT_PSUM
             )

@@ -180,7 +180,7 @@ def dispatch_sanity(m: int = 4096, k: int = 512, n: int = 8):
              "shard_map", 2),
         ]
         for name, pol, expect, knob in mesh_arms:
-            with mesh:
+            with jax.set_mesh(mesh):
                 _, log = jit_isolated(lambda x_, y_: tsmm.tsmm_t(x_, y_),
                                       x, y, policy=pol)
             observed = sorted({e.executor for e in log})

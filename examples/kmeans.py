@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import tsmm
+from repro.launch.cache import configure_compilation_cache
 
 N, D, K, ITERS = 200_000, 64, 8, 10
 
@@ -72,6 +73,7 @@ def kmeanspp_init(key, x):
 
 
 def main():
+    configure_compilation_cache()
     key = jax.random.PRNGKey(0)
     x, true_centers = make_blobs(key)
     step = jax.jit(kmeans_step)

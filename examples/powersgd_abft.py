@@ -14,7 +14,10 @@ import jax.numpy as jnp
 
 from repro.core import tsmm
 from repro.ft import abft
+from repro.launch.cache import configure_compilation_cache
 from repro.optim import powersgd
+
+configure_compilation_cache()
 
 key = jax.random.PRNGKey(0)
 

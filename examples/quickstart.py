@@ -14,6 +14,9 @@ import numpy as np
 
 from repro.core import perf_model, tsmm
 from repro.kernels import ref
+from repro.launch.cache import configure_compilation_cache
+
+configure_compilation_cache()
 
 key = jax.random.PRNGKey(0)
 

@@ -26,6 +26,7 @@ import numpy as np
 
 from repro import linalg
 from repro.core import tsmm
+from repro.launch.cache import configure_compilation_cache
 
 N, D, RANK, OVERSAMPLE, POWER_ITERS = 200_000, 256, 8, 8, 2
 
@@ -57,6 +58,7 @@ def rsvd(key, a, rank, *, oversample=OVERSAMPLE, power_iters=POWER_ITERS):
 
 
 def main():
+    configure_compilation_cache()
     key = jax.random.PRNGKey(0)
     a, s_true = make_low_rank(key)
     t0 = time.time()

@@ -13,11 +13,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import registry
+from repro.launch.cache import configure_compilation_cache
 from repro.models import model
 from repro.serve import engine
 
 
 def main():
+    configure_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-3b",
                     choices=[a for a in registry.ARCH_NAMES

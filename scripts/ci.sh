@@ -13,10 +13,10 @@ if [[ "${REPRO_CI_INSTALL:-0}" == "1" ]] \
 fi
 
 # REPRO_PYTEST_XDIST=auto (or an int) parallelizes the run via
-# pytest-xdist when it is installed -- CI's latest-jax leg sets it to keep
-# wall-clock flat as the suite grows; the oldest-pin leg stays serial as
-# the deterministic reference. -x is dropped under xdist (fail-fast and
-# worker scheduling don't compose; failures still fail the run).
+# pytest-xdist when it is installed -- CI's tier-1 job sets it to keep
+# wall-clock flat as the suite grows; unset, the run is serial. -x is
+# dropped under xdist (fail-fast and worker scheduling don't compose;
+# failures still fail the run).
 XDIST="${REPRO_PYTEST_XDIST:-}"
 if [[ -n "$XDIST" ]] && python -c "import xdist" 2>/dev/null; then
     PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \

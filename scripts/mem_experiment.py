@@ -44,7 +44,7 @@ for name, cfg in variants.items():
         b_named = sharding.named(mesh, sharding.batch_specs(cfg, mesh, batch_shape))
         step_fn = ts.make_train_step(cfg, opt_cfg, n_micro=cfg.microbatch,
                                      acc_shardings=p_named)
-        with mesh:
+        with jax.set_mesh(mesh):
             comp = jax.jit(step_fn, in_shardings=(state_named, b_named),
                            out_shardings=(state_named, None),
                            donate_argnums=(0,)).lower(state_shape, batch_shape).compile()

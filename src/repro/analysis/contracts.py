@@ -41,6 +41,7 @@ __all__ = [
     "bytes_per_elem",
     "min_sublane",
     "vmem_budget",
+    "vmem_limit_bytes",
     "tsm2r_footprint",
     "tsm2l_footprint",
     "tsmt_footprint",
@@ -126,6 +127,16 @@ def min_sublane(spec, dtype) -> int:
 def vmem_budget(spec) -> float:
     """Bytes of VMEM the pipeliner may use under ``spec``."""
     return spec.vmem_bytes * spec.vmem_usable
+
+
+def vmem_limit_bytes(spec) -> int:
+    """The scoped-VMEM limit every kernel launch passes to Mosaic
+    (``CompilerParams(vmem_limit_bytes=...)``): exactly the budget the
+    block choosers sized the windows against, so a config the model calls
+    feasible is one the compiler accepts. Mosaic's own default scoped
+    limit (16 MiB) is far below the chooser's budget, and large windows
+    were refused at compile time before the two were tied together."""
+    return int(vmem_budget(spec))
 
 
 # ---------------------------------------------------------------------------

@@ -136,10 +136,15 @@ def capture_kernel(kind, padded_shape, params, dtype, *, quant="none"
     ``kind="reduce"`` has no quantized variant (split partials are f32
     either way).
     """
+    from repro.core import perf_model
     from repro.kernels import quant as kquant
     from repro.kernels import reduce as kreduce
     from repro.kernels import tsm2l, tsm2r, tsmt
 
+    # Launch-only arguments: nothing is compiled here, so the default
+    # spec's limit stands in for whichever spec resolved ``params``.
+    launch = {"interpret": True,
+              "vmem_limit_bytes": contracts.vmem_limit_bytes(perf_model.V5E)}
     p = dict(params)
     s = p.get("splits", 1)
     dtype = jnp.dtype(dtype)
@@ -158,25 +163,25 @@ def capture_kernel(kind, padded_shape, params, dtype, *, quant="none"
                 fn = functools.partial(_unjit(kquant.tsm2r_q8_pallas),
                                        out_dtype=dtype,
                                        block_m=p["block_m"],
-                                       block_k=p["block_k"], interpret=True)
+                                       block_k=p["block_k"], **launch)
             else:
                 # Split partials are f32 regardless of caller dtype.
                 fn = functools.partial(_unjit(kquant.tsm2r_q8_pallas_split),
                                        block_m=p["block_m"],
                                        block_k=p["block_k"], splits=s,
-                                       interpret=True)
+                                       **launch)
         else:
             args = (jax.ShapeDtypeStruct((m, k), dtype),
                     jax.ShapeDtypeStruct((k, n), dtype))
             if s == 1:
                 fn = functools.partial(_unjit(tsm2r.tsm2r_pallas),
                                        block_m=p["block_m"],
-                                       block_k=p["block_k"], interpret=True)
+                                       block_k=p["block_k"], **launch)
             else:
                 fn = functools.partial(_unjit(tsm2r.tsm2r_pallas_split),
                                        block_m=p["block_m"],
                                        block_k=p["block_k"], splits=s,
-                                       interpret=True)
+                                       **launch)
     elif kind == "tsm2l":
         m, k, n = padded_shape
         if q8:
@@ -186,12 +191,12 @@ def capture_kernel(kind, padded_shape, params, dtype, *, quant="none"
                     jax.ShapeDtypeStruct((1, 1), f32))
             fn = functools.partial(_unjit(kquant.tsm2l_q8_pallas),
                                    out_dtype=dtype, block_m=p["block_m"],
-                                   interpret=True)
+                                   **launch)
         else:
             args = (jax.ShapeDtypeStruct((m, k), dtype),
                     jax.ShapeDtypeStruct((k, n), dtype))
             fn = functools.partial(_unjit(tsm2l.tsm2l_pallas),
-                                   block_m=p["block_m"], interpret=True)
+                                   block_m=p["block_m"], **launch)
     elif kind == "tsmt":
         m, a, b = padded_shape
         if q8:
@@ -203,31 +208,31 @@ def capture_kernel(kind, padded_shape, params, dtype, *, quant="none"
                 fn = functools.partial(_unjit(kquant.tsmt_q8_pallas),
                                        out_dtype=dtype,
                                        block_m=p["block_m"],
-                                       block_a=p["block_a"], interpret=True)
+                                       block_a=p["block_a"], **launch)
             else:
                 # Split partials are f32 regardless of caller dtype.
                 fn = functools.partial(_unjit(kquant.tsmt_q8_pallas_split),
                                        block_m=p["block_m"],
                                        block_a=p["block_a"], splits=s,
-                                       interpret=True)
+                                       **launch)
         else:
             args = (jax.ShapeDtypeStruct((m, a), dtype),
                     jax.ShapeDtypeStruct((m, b), dtype))
             if s == 1:
                 fn = functools.partial(_unjit(tsmt.tsmt_pallas),
                                        block_m=p["block_m"],
-                                       block_a=p["block_a"], interpret=True)
+                                       block_a=p["block_a"], **launch)
             else:
                 fn = functools.partial(_unjit(tsmt.tsmt_pallas_split),
                                        block_m=p["block_m"],
                                        block_a=p["block_a"], splits=s,
-                                       interpret=True)
+                                       **launch)
     elif kind == "reduce":
         stack, rows, cols = padded_shape
         args = (jax.ShapeDtypeStruct((stack, rows, cols), jnp.float32),)
         fn = functools.partial(_unjit(kreduce.sum_partials_pallas),
                                block_r=p["block_r"], out_dtype=dtype,
-                               interpret=True)
+                               **launch)
     else:
         raise ValueError(f"unknown kernel kind {kind!r}")
 

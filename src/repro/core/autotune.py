@@ -468,9 +468,8 @@ def _operands(kind: str, m: int, d1: int, d2: int, dtype, seed: int = 0):
 
 
 def _resolved_executor(policy) -> str:
-    interpret = (compat.auto_interpret() if policy.interpret is None
-                 else policy.interpret)
-    return "interpret" if interpret else "pallas-tpu"
+    return ("interpret" if compat.auto_interpret(policy.interpret)
+            else "pallas-tpu")
 
 
 def autotune_shape(kind: str, m: int, d1: int, d2: int, *,

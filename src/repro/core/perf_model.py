@@ -29,6 +29,7 @@ import dataclasses
 import math
 from typing import Literal
 
+import jax
 import jax.numpy as jnp
 
 from repro.analysis import contracts
@@ -80,6 +81,7 @@ V5P = TPUSpec(
     peak_flops_f32=459e12 / 4,
     hbm_bw=2765e9,
     ici_bw_per_link=100e9,
+    vmem_bytes=64 * 2**20,  # per TensorCore (v5e has 128 MiB)
     n_cores=2,  # megacore: Mosaic splits parallel grid dims across 2 cores
 )
 
@@ -89,6 +91,37 @@ SPECS: dict[str, TPUSpec] = {
     "tpu_v5p": V5P,
     "v5p": V5P,
 }
+
+
+# Specs keyed by ``jax.Device.device_kind``, the strings the TPU runtime
+# reports (Pallas' own ``tpu_info`` table matches on the same ones): a v5e
+# calls itself "TPU v5 lite", a v5p "TPU v5".
+DEVICE_KIND_SPECS: dict[str, TPUSpec] = {
+    "TPU v5 lite": V5E,
+    "TPU v5e": V5E,
+    "TPU v5": V5P,
+    "TPU v5p": V5P,
+}
+
+
+def device_spec(device=None) -> TPUSpec:
+    """The spec of ``device`` (default: ``jax.devices()[0]``).
+
+    On a TPU the spec comes from :data:`DEVICE_KIND_SPECS` by
+    ``device_kind``, and a kind the table does not know is an error -- a
+    guessed spec would size kernel blocks for the wrong chip. Off-TPU (the
+    CPU test backend, where kernels run in interpret mode) the modelled
+    default is V5E, the benchmark's chip."""
+    d = device if device is not None else jax.devices()[0]
+    if d.platform != "tpu":
+        return V5E
+    try:
+        return DEVICE_KIND_SPECS[d.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no TPUSpec for device_kind {d.device_kind!r}: known kinds are "
+            f"{sorted(DEVICE_KIND_SPECS)} -- add the chip's peaks to "
+            "repro.core.perf_model") from None
 
 
 def get_spec(name: str) -> TPUSpec:

@@ -36,7 +36,7 @@ shape to an executor:
 * ``shard_map``   -- wraps the dispatch per-shard over the data-parallel
   mesh axes, so per-device shapes stay tall-and-skinny under DP. This
   replaces the old hard guard that sent every call under a multi-chip
-  ``with mesh:`` scope to the dense path: when the tall dim divides the DP
+  ``jax.set_mesh(mesh)`` scope to the dense path: when the tall dim divides the DP
   axes and the per-shard shape still classifies tall-skinny, the kernels
   now run per shard (``tsmm_t`` reduces the per-shard partial products
   per ``GemmPolicy.reduce``: psum by default, stacked partials on
@@ -186,8 +186,12 @@ class GemmPolicy:
     entry on auto (so VJP re-dispatch stays shape-correct).
 
     ``interpret``: tri-state Pallas interpret flag (None = auto-detect:
-    interpret off-TPU). ``spec``: the hardware model driving block-size
-    choice (see ``perf_model.SPECS``). ``param_dtype_grads``: emit parameter
+    interpret off-TPU, compiled on a TPU; only an explicit True selects
+    interpret on a TPU). ``spec``: the hardware model driving block-size
+    choice and the kernels' scoped-VMEM limit; None (the default) resolves
+    at construction to the spec of the device JAX runs on
+    (``perf_model.device_spec``: by ``device_kind`` on a TPU, an error for
+    an unknown chip, V5E off-TPU). ``param_dtype_grads``: emit parameter
     gradients in the parameter dtype instead of f32 (halves per-device grad
     memory under pure-DP/ZeRO-1; accumulation inside each dot stays f32).
 
@@ -311,7 +315,7 @@ class GemmPolicy:
     """
 
     mode: str = "auto"
-    spec: perf_model.TPUSpec = perf_model.V5E
+    spec: perf_model.TPUSpec | None = None
     skinny_ratio: int = SKINNY_RATIO
     max_skinny: int = MAX_SKINNY
     min_tall: int = MIN_TALL
@@ -337,6 +341,8 @@ class GemmPolicy:
     verify_contracts: bool = False
 
     def __post_init__(self):
+        if self.spec is None:
+            object.__setattr__(self, "spec", perf_model.device_spec())
         s = self.split
         if not (s in ("auto", "never")
                 or (isinstance(s, int) and not isinstance(s, bool)
@@ -400,13 +406,18 @@ def _policy_from_env() -> GemmPolicy:
     return GemmPolicy(**kw)
 
 
-_DEFAULT_POLICY = _policy_from_env()
+# Built on first use, not at import: resolving the default spec asks JAX
+# for its devices, and importing the dispatcher must not start a backend.
+_DEFAULT_POLICY: GemmPolicy | None = None
 _POLICY_VAR: contextvars.ContextVar[GemmPolicy | None] = \
     contextvars.ContextVar("repro_gemm_policy", default=None)
 
 
 def default_policy() -> GemmPolicy:
     """The process-default policy (env-var aliases applied)."""
+    global _DEFAULT_POLICY
+    if _DEFAULT_POLICY is None:
+        _DEFAULT_POLICY = _policy_from_env()
     return _DEFAULT_POLICY
 
 
@@ -420,7 +431,7 @@ def refresh_default_policy() -> GemmPolicy:
 def current_policy() -> GemmPolicy:
     """The innermost active ``with tsmm.policy(...)`` scope, else the
     process default."""
-    return _POLICY_VAR.get() or _DEFAULT_POLICY
+    return _POLICY_VAR.get() or default_policy()
 
 
 @contextlib.contextmanager
@@ -782,7 +793,7 @@ def _shard_map_env(p: GemmPolicy):
     mesh = compat.get_context_mesh()
     if mesh is None:
         raise RuntimeError("shard_map executor requires an active "
-                           "`with mesh:` scope")
+                           "`jax.set_mesh(mesh)` scope")
     dp = _dp_axes(mesh, p)
     if not dp:
         raise RuntimeError(

@@ -277,9 +277,7 @@ def cache_specs(cfg, mesh: Mesh, cache_shape):
 
 
 def _context_mesh():
-    """The `with mesh:` context mesh, or None (abstract mesh is empty under
-    plain `with mesh:` -- the compat shim reads the physical thread
-    resources through the public interpreters API)."""
+    """The ``jax.set_mesh`` context mesh (abstract), or None outside one."""
     return compat.get_context_mesh()
 
 

@@ -56,18 +56,47 @@ from repro.analysis import contracts as _contracts
 from repro.core import tsmm
 
 
-def checksum_weights(d1: int, s: int = 2) -> jnp.ndarray:
-    """Huang-Abraham style: [1, (i+1)/d, ((i+1)/d)^2, ...] columns, f32.
+def _radix(d1: int) -> tuple[int, int]:
+    """(period, blocks) of the mixed-radix row code: ``period`` =
+    ceil(sqrt(d1)) rows per block, ``blocks`` = ceil(d1 / period)."""
+    p = max(1, math.isqrt(d1 - 1) + 1) if d1 > 1 else 1
+    return p, -(-d1 // p)
 
-    Column 0 (ones) carries the error magnitude; column 1 (the ramp)
-    carries it scaled by the row position, so ``delta1/delta0 = (i+1)/d``
-    localizes a single faulty row. f32 always: a low-precision ramp would
-    blur exactly the ratio the locate step divides."""
-    i = jnp.arange(d1, dtype=jnp.float32)
-    cols = [jnp.ones((d1,), jnp.float32), (i + 1.0) / d1]
+
+def _weights_at(i, d1: int, s: int) -> jnp.ndarray:
+    """Checksum weights of row(s) ``i`` (int array, traced or not):
+    ``(..., s)`` f32, the rows of :func:`checksum_weights`."""
+    i = jnp.asarray(i, jnp.float32)
+    ones = jnp.ones_like(i)
+    if s == 2:
+        cols = [ones, (i + 1.0) / d1]
+    else:
+        p, nb = _radix(d1)
+        block = jnp.floor(i / p)
+        cols = [ones, (i - block * p + 1.0) / p, (block + 1.0) / nb]
     while len(cols) < s:
         cols.append(jnp.square(cols[-1]))
-    return jnp.stack(cols[:s], axis=1)
+    return jnp.stack(cols[:s], axis=-1)
+
+
+def checksum_weights(d1: int, s: int = 2) -> jnp.ndarray:
+    """Huang-Abraham style weight columns, ``(d1, s)`` f32.
+
+    Column 0 (ones) carries the error magnitude; the ramp columns carry it
+    scaled by the row position, so their ratios to the plain delta
+    localize a single faulty row. ``s == 2``: one ramp ``(i+1)/d1``. Its
+    ratio is read against the f32 rounding of a checksum whose magnitude
+    grows like ``sqrt(d1)``, so over tens of thousands of rows a moderate
+    fault lands a row or more away. ``s >= 3``: a mixed-radix code -- a
+    fine ramp ``((i mod P)+1)/P`` and a coarse ramp ``(i//P + 1)/B`` with
+    ``P = B ~ sqrt(d1)`` -- which resolves each digit ``sqrt(d1)`` times
+    more finely (the offline parameter path, where ``d1`` is a whole
+    leaf). Further columns square the last one. f32 always: a
+    low-precision ramp would blur exactly the ratio the locate step
+    divides."""
+    if s < 2:
+        raise ValueError(f"checksum_weights: s={s} < 2 cannot localize")
+    return _weights_at(jnp.arange(d1), d1, s)
 
 
 # Internal alias kept for call sites that predate the public name.
@@ -128,8 +157,10 @@ def detect(c_out, c_ref, *, rows: int, reduction: int, eps: float, amax):
     return ~ok, tol
 
 
-def encode_leaf(x, s: int = 2, *, policy=None, interpret=None):
-    """Checksum of one 2-D (or reshaped) array: (cols, s) f32.
+def encode_leaf(x, s: int = 3, *, policy=None, interpret=None):
+    """Checksum of one 2-D (or reshaped) array: (cols, s) f32. The default
+    ``s=3`` is the mixed-radix code of :func:`checksum_weights`, which
+    localizes a single faulty row of a whole parameter leaf exactly.
 
     ``policy`` pins a GemmPolicy for the TSMT pass (defaults to the active
     ``tsmm.policy(...)`` scope); ``interpret=`` is the deprecated alias.
@@ -144,7 +175,7 @@ def encode_leaf(x, s: int = 2, *, policy=None, interpret=None):
                        interpret=interpret)
 
 
-def encode_tree(tree, s: int = 2, *, policy=None, interpret=None):
+def encode_tree(tree, s: int = 3, *, policy=None, interpret=None):
     """Checksums for every leaf with >= 2 dims and >= 2^16 elements."""
     def one(x):
         if x.ndim < 1 or x.size < 65536:
@@ -216,7 +247,8 @@ def locate_and_correct(out, c_out, c_ref, *, rows: int, reduction: int,
 
     ``c_out`` is the checksum computed FROM the output, ``c_ref`` the
     reference pushed through the operands -- both ``(cols, s>=2)`` f32
-    with plain weights in column 0 and the ramp in column 1. ``mode``:
+    with the columns of :func:`checksum_weights` (plain weights in column
+    0, the ramp or the mixed-radix digits after it). ``mode``:
 
     * "verify"  -- clean: return ``out`` unchanged (bit-identical);
       fault: return ``out`` fully NaN-poisoned, so any downstream
@@ -257,15 +289,26 @@ def locate_and_correct(out, c_out, c_ref, *, rows: int, reduction: int,
     if mode == "verify":
         return poisoned
 
-    d0 = c_out[:, 0] - c_ref[:, 0]
-    d1 = c_out[:, 1] - c_ref[:, 1]
-    # Anchor on the worst finite bad column; its ramp/plain ratio is the
-    # faulty row's weight (i+1)/rows.
+    d = c_out - c_ref
+    d0 = d[:, 0]
+    # Anchor on the worst finite bad column; its ramp/plain ratios are the
+    # faulty row's weights (one ramp, or the fine and coarse digits).
     mag = jnp.where(bad & jnp.isfinite(d0), jnp.abs(d0), -jnp.inf)
     j = jnp.argmax(mag)
-    ratio = d1[j] / d0[j]
-    i_f = jnp.round(ratio * rows) - 1.0
-    i_ok = jnp.isfinite(ratio) & (i_f >= 0.0) & (i_f <= rows - 1.0)
+    s = 2 if c_out.shape[1] == 2 else 3
+    if s == 2:
+        ratio = d[j, 1] / d0[j]
+        i_f = jnp.round(ratio * rows) - 1.0
+        digits_ok = jnp.isfinite(ratio)
+    else:
+        p, nb = _radix(rows)
+        fine = jnp.round(d[j, 1] / d0[j] * p) - 1.0
+        coarse = jnp.round(d[j, 2] / d0[j] * nb) - 1.0
+        i_f = coarse * p + fine
+        digits_ok = ((fine >= 0.0) & (fine <= p - 1.0)
+                     & (coarse >= 0.0) & (coarse <= nb - 1.0))
+    i_ok = (digits_ok & jnp.isfinite(i_f) & (i_f >= 0.0)
+            & (i_f <= rows - 1.0))
     i = jnp.clip(jnp.where(jnp.isfinite(i_f), i_f, 0.0), 0,
                  rows - 1).astype(jnp.int32)
     row = lax.dynamic_slice_in_dim(out, i, 1, axis=0)[0]
@@ -276,19 +319,20 @@ def locate_and_correct(out, c_out, c_ref, *, rows: int, reduction: int,
     fix_cols = bad & jnp.isfinite(est)
     snapped = _snap_to_bitflip(row, est, 4.0 * tol)
     fixed = lax.stop_gradient(jnp.where(fix_cols, snapped, row))
-    # Residual: a correct single-row repair must cancel BOTH deviations
-    # in every column (bad and clean alike -- a multi-row fault leaves
+    # Residual: a correct single-row repair must cancel every checksum
+    # deviation in every column (bad and clean alike -- a multi-row fault leaves
     # the other rows' contribution standing and fails here). The gate
     # widens by the f32 cancellation floor of the quantities it
-    # subtracts: d0 and delta are each rounded at their own magnitude,
-    # so their sum is only meaningful down to ~eps * (|d0| + |delta|).
+    # subtracts: d and delta are each rounded at their own magnitude,
+    # so their sum is only meaningful down to ~eps * (|d| + |delta|).
     delta = fixed.astype(f32) - row.astype(f32)
-    w_ramp = (i.astype(f32) + 1.0) / rows
+    w = _weights_at(i, rows, s)
     f32_eps = jnp.float32(jnp.finfo(jnp.float32).eps)
-    cancel0 = 32.0 * f32_eps * (jnp.abs(d0) + jnp.abs(delta))
-    cancel1 = 32.0 * f32_eps * (jnp.abs(d1) + jnp.abs(w_ramp * delta))
-    res_ok = jnp.all((jnp.abs(d0 + delta) <= 4.0 * tol + cancel0)
-                     & (jnp.abs(d1 + w_ramp * delta) <= 4.0 * tol + cancel1))
+    res_ok = jnp.bool_(True)
+    for col in range(s):
+        shift = w[col] * delta
+        cancel = 32.0 * f32_eps * (jnp.abs(d[:, col]) + jnp.abs(shift))
+        res_ok &= jnp.all(jnp.abs(d[:, col] + shift) <= 4.0 * tol + cancel)
     corrected = lax.dynamic_update_slice_in_dim(out, fixed[None, :], i,
                                                 axis=0)
     good = i_ok & res_ok

@@ -1,63 +1,37 @@
-"""JAX/Pallas version-compatibility layer.
+"""The repo's one seam onto JAX/Pallas spellings (written for JAX 0.9.0).
 
-Every symbol that has drifted across the JAX versions this repo must run on
-is resolved here, once, at import time. Kernel/model/test code imports from
-this module instead of guessing which spelling the installed JAX uses.
+Kernel/model/test code imports these names from here instead of spelling
+the JAX API at each call site, so a future JAX move is one edit:
 
-Shims and the version ranges they cover:
-
-* ``CompilerParams`` -- the Mosaic compiler-params class.
-  ``pltpu.TPUCompilerParams`` on jax 0.4.30 -- 0.6.x; renamed to
-  ``pltpu.CompilerParams`` in 0.7. Resolution order prefers the new name.
-* ``VMEM`` -- the TPU memory-space handle used for scratch shapes.
-  Present as ``pltpu.VMEM`` on every covered version; on very old releases
-  it lived on ``pltpu.TPUMemorySpace.VMEM`` (fallback kept for 0.4.2x).
-* ``abstract_mesh(axis_sizes, axis_names)`` -- ``jax.sharding.AbstractMesh``
-  construction. 0.4.3x takes one ``((name, size), ...)`` shape tuple;
-  0.5+ takes ``(axis_sizes, axis_names)`` positionally. The helper accepts
-  the modern calling convention and translates when needed.
-* ``optimization_barrier`` -- ``jax.lax.optimization_barrier`` has no
-  differentiation rule before jax 0.5.1 (jax-ml/jax#25392). On those
-  versions we wrap it in a ``jax.custom_vjp`` identity whose backward
-  re-applies the barrier to the cotangent, so reverse-mode keeps the same
-  hoisting protection the primal asked for. On newer JAX the native
-  primitive (which differentiates) is used directly.
-* ``make_mesh(shape, axis_names)`` -- ``jax.make_mesh`` grew the
-  ``axis_types`` kwarg (and ``jax.sharding.AxisType``) in 0.5; on 0.4.3x
-  the kwarg does not exist and Auto is the only behavior. The helper
-  passes explicit-Auto types only where the installed JAX has them.
-* ``get_context_mesh()`` -- the ``with mesh:`` context mesh, read through
-  the public ``jax.interpreters.pxla`` surface (the dispatcher must never
-  import ``jax._src``). Returns None outside a mesh scope.
+* ``CompilerParams`` / ``VMEM`` -- the Mosaic compiler-params class and the
+  VMEM scratch memory space (``pltpu.CompilerParams`` / ``pltpu.VMEM``).
+* ``abstract_mesh(axis_sizes, axis_names)`` and ``make_mesh(shape,
+  axis_names)`` -- meshes with explicit ``AxisType.Auto`` axes.
+* ``get_context_mesh()`` -- the mesh set by ``jax.set_mesh(mesh)``, read as
+  ``jax.sharding.get_abstract_mesh()`` (valid both eagerly and inside
+  ``jit``); None outside one, and None inside a ``shard_map`` body, whose
+  axes are manual (per-shard code dispatches on its local shapes).
 * ``mesh_axis_sizes(mesh)`` -- ``{axis_name: size}`` for a Mesh or
-  AbstractMesh. ``mesh.shape`` is an OrderedDict on the versions covered
-  but has drifted (plain dict / ``axis_sizes`` tuple) -- callers that only
-  need names x sizes go through this instead of touching ``.shape``.
-* ``shard_map(...)`` -- lived in ``jax.experimental.shard_map`` through
-  0.5.x and moved to ``jax.shard_map`` later; ``check_rep`` was also
-  renamed away. The wrapper takes the modern keyword signature and drops
-  kwargs the installed JAX rejects.
-* ``psum_scatter(x, axis)`` / ``all_gather(x, axis)`` -- the collective
-  pair the sharded-output ``tsmm_t`` path is built on.
-  ``lax.psum_scatter(..., tiled=True)`` has been stable since well before
-  0.4.30, but the ``tiled`` kwarg is the part most likely to drift (it
-  already changed semantics once in jax's history), so both wrappers pin
-  the tiled calling convention here and fall back to an explicit
-  psum+slice / concat emulation if the installed JAX rejects it.
-* ``auto_interpret()`` -- the Pallas interpret-mode default: kernel bodies
-  run in Python off-TPU (correctness on CPU), compile via Mosaic on TPU.
+  AbstractMesh.
+* ``shard_map(...)`` -- ``jax.shard_map`` with ``check_vma=False`` (the
+  psum-producing dispatch bodies are not replication-typed).
+* ``psum_scatter(x, axis)`` / ``all_gather(x, axis)`` -- the collective pair
+  the sharded-output ``tsmm_t`` path is built on, pinned to the tiled
+  convention.
+* ``auto_interpret(requested)`` -- the ONE place the Pallas interpret mode
+  is chosen: an explicit True/False wins (tests set True to run kernel
+  bodies in Python on the CPU); None means interpret exactly when the
+  default backend is not a TPU. On a TPU only an explicit setting selects
+  interpret.
 * ``pallas_call(...)`` / ``capture_launches()`` -- the launch-recording
   shim. Every in-repo kernel routes its ``pl.pallas_call`` through
   :func:`pallas_call`, which is a zero-overhead pass-through outside a
   :func:`capture_launches` scope and otherwise records a
   :class:`LaunchCapture` (grid, BlockSpec block shapes + index-map
   callables, dimension_semantics, operand/out/scratch avals, the kernel
-  fn) per invocation. ``repro.analysis.kernel_verify`` drives the kernel
-  entry points under ``jax.eval_shape`` inside such a scope to verify the
-  grid dataflow statically -- no device, no compile.
-
-The probes are trace-time only (``jax.eval_shape``): importing this module
-never compiles or executes device code.
+  fn, the interpret flag) per invocation. ``repro.analysis.kernel_verify``
+  drives the kernel entry points under ``jax.eval_shape`` inside such a
+  scope to verify the grid dataflow statically -- no device, no compile.
 """
 
 from __future__ import annotations
@@ -75,8 +49,6 @@ __all__ = [
     "VMEM",
     "abstract_mesh",
     "make_mesh",
-    "optimization_barrier",
-    "BARRIER_IS_DIFFERENTIABLE",
     "get_context_mesh",
     "mesh_axis_sizes",
     "shard_map",
@@ -89,143 +61,56 @@ __all__ = [
     "pallas_call",
 ]
 
+CompilerParams = pltpu.CompilerParams
+VMEM = pltpu.VMEM
 
-def auto_interpret() -> bool:
-    """Pallas interpret-mode default: Python bodies off-TPU, Mosaic on TPU."""
+
+def auto_interpret(requested: bool | None = None) -> bool:
+    """Pallas interpret mode: ``requested`` when given, else interpret
+    exactly when the default backend is not a TPU."""
+    if requested is not None:
+        return bool(requested)
     return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
-# Mosaic compiler params: pltpu.CompilerParams (new) vs TPUCompilerParams
-# ---------------------------------------------------------------------------
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams", None)
-if CompilerParams is None:  # pragma: no cover - ancient pallas
-    raise ImportError(
-        "pallas TPU backend exposes neither CompilerParams nor "
-        "TPUCompilerParams; need jax >= 0.4.30")
-
-
-# ---------------------------------------------------------------------------
-# VMEM scratch memory space
-# ---------------------------------------------------------------------------
-
-VMEM = getattr(pltpu, "VMEM", None)
-if VMEM is None:  # pragma: no cover - pre-0.4.30 spelling
-    VMEM = pltpu.TPUMemorySpace.VMEM
-
-
-# ---------------------------------------------------------------------------
-# AbstractMesh construction
+# Meshes
 # ---------------------------------------------------------------------------
 
 def abstract_mesh(axis_sizes: tuple[int, ...], axis_names: tuple[str, ...]):
-    """``AbstractMesh((16, 16), ("data", "model"))`` on every covered JAX.
-
-    jax >= 0.5 takes exactly this signature; 0.4.3x wants a single
-    ``((name, size), ...)`` tuple instead, which raises
-    ``TypeError: 'int' object is not iterable`` when handed bare sizes.
-    """
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    """``AbstractMesh((16, 16), ("data", "model"))`` with Auto axes."""
+    from jax.sharding import AbstractMesh, AxisType
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names),
+                        axis_types=(AxisType.Auto,) * len(axis_names))
 
 
-def make_mesh(shape: tuple[int, ...], axis_names: tuple[str, ...]):
-    """``jax.make_mesh`` with explicit-Auto axis types where supported.
-
-    jax >= 0.5 wants ``axis_types=(AxisType.Auto, ...)`` spelled out (the
-    default flipped during the explicit-sharding rollout); 0.4.3x has
-    neither the kwarg nor ``jax.sharding.AxisType`` and is always Auto.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axis_names,
-                             axis_types=(axis_type.Auto,) * len(axis_names))
-    return jax.make_mesh(shape, axis_names)
-
-
-# ---------------------------------------------------------------------------
-# Mesh-context introspection + shard_map
-# ---------------------------------------------------------------------------
-
-def _resolve_thread_resources():
-    """Probe the public pxla re-export once at import. A failed probe is a
-    version-drift event worth a warning, NOT silently equivalent to
-    "no mesh active": the dispatcher's multi-chip guard depends on it."""
-    try:
-        from jax.interpreters import pxla
-        pxla.thread_resources.env.physical_mesh  # full attribute path
-        return pxla.thread_resources
-    except Exception:  # pragma: no cover - future-JAX drift
-        import warnings
-        warnings.warn(
-            "jax.interpreters.pxla.thread_resources is unavailable on this "
-            "JAX; mesh-context detection (and the tsmm multi-chip dispatch "
-            "guard) is disabled -- extend repro.kernels.compat for this "
-            "version", RuntimeWarning, stacklevel=2)
-        return None
-
-
-_THREAD_RESOURCES = _resolve_thread_resources()
+def make_mesh(shape: tuple[int, ...], axis_names: tuple[str, ...],
+              devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` (GSPMD-style),
+    over ``devices`` (default: all of them)."""
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names), devices=devices)
 
 
 def get_context_mesh():
-    """The active ``with mesh:`` context mesh, or None outside one.
-
-    Read through ``jax.interpreters.pxla`` (public re-export) -- the
-    abstract mesh is empty under a plain ``with mesh:`` scope, so the
-    physical thread resources are the only reliable signal across the
-    covered JAX versions.
-    """
-    if _THREAD_RESOURCES is None:
+    """The ``jax.set_mesh`` context mesh (an AbstractMesh), or None outside
+    one and inside a ``shard_map`` body (manual axes)."""
+    m = jax.sharding.get_abstract_mesh()
+    if m.empty or m.manual_axes:
         return None
-    m = _THREAD_RESOURCES.env.physical_mesh
-    return m if m.axis_names else None
-
-
-def _resolve_shard_map():
-    try:
-        from jax.experimental.shard_map import shard_map as f  # <= 0.5.x
-        return f
-    except ImportError:  # pragma: no cover - moved in newer JAX
-        from jax import shard_map as f
-        return f
-
-
-_SHARD_MAP = _resolve_shard_map()
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, on every covered JAX.
-
-    ``check_rep=False`` keeps psum-producing bodies legal on 0.4.x/0.5.x;
-    newer JAX renamed/removed the kwarg, so it is dropped on TypeError.
-    """
-    try:
-        return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:  # pragma: no cover - post-rename JAX
-        return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+    return m
 
 
 def mesh_axis_sizes(mesh) -> dict:
-    """``{axis_name: size}`` for a Mesh/AbstractMesh, tolerant of the
-    ``.shape`` container drifting (OrderedDict today; ``axis_sizes`` tuple
-    on the explicit-sharding branches)."""
-    shape = getattr(mesh, "shape", None)
-    if shape is not None and hasattr(shape, "items"):
-        return dict(shape)
-    sizes = getattr(mesh, "axis_sizes", None)  # pragma: no cover - drift
-    if sizes is not None:
-        return dict(zip(mesh.axis_names, sizes))
-    raise TypeError(  # pragma: no cover - future-JAX drift
-        f"cannot read axis sizes off mesh {mesh!r}; extend "
-        "repro.kernels.compat.mesh_axis_sizes for this JAX version")
+    """``{axis_name: size}`` for a Mesh or AbstractMesh."""
+    return dict(mesh.shape)
+
+
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with replication (vma) checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -233,92 +118,20 @@ def mesh_axis_sizes(mesh) -> dict:
 # ---------------------------------------------------------------------------
 
 def psum_scatter(x, axis_name, *, scatter_dimension: int = 0):
-    """Tiled reduce-scatter over ``axis_name`` (a name or tuple of names).
-
-    Semantics pinned here: the *global* result equals ``lax.psum(x, axis)``
-    with each shard keeping only its ``scatter_dimension`` slab -- i.e.
-    ``lax.psum_scatter(..., tiled=True)``. Requires
+    """Tiled reduce-scatter over ``axis_name`` (a name or tuple of names):
+    the *global* result equals ``lax.psum(x, axis)`` with each shard keeping
+    only its ``scatter_dimension`` slab. Requires
     ``x.shape[scatter_dimension]`` divisible by the axis size (callers
-    check; the tsmm dispatcher falls back to dense when it doesn't).
-    """
-    try:
-        return jax.lax.psum_scatter(x, axis_name,
-                                    scatter_dimension=scatter_dimension,
-                                    tiled=True)
-    except TypeError:  # pragma: no cover - tiled-kwarg drift
-        summed = jax.lax.psum(x, axis_name)
-        idx = _flat_axis_index(axis_name)
-        size = jax.lax.psum(1, axis_name)
-        slab = x.shape[scatter_dimension] // size
-        return jax.lax.dynamic_slice_in_dim(summed, idx * slab, slab,
-                                            axis=scatter_dimension)
+    check; the tsmm dispatcher falls back to dense when it doesn't)."""
+    return jax.lax.psum_scatter(x, axis_name,
+                                scatter_dimension=scatter_dimension,
+                                tiled=True)
 
 
 def all_gather(x, axis_name, *, axis: int = 0):
     """Tiled all-gather over ``axis_name``: shards concatenate along
     ``axis`` (the inverse of :func:`psum_scatter` on the same axis)."""
-    try:
-        return jax.lax.all_gather(x, axis_name, axis=axis, tiled=True)
-    except TypeError:  # pragma: no cover - tiled-kwarg drift
-        # Untiled all_gather inserts a new leading dim of the axis size at
-        # position ``axis``; tiled merges it into the next dim.
-        stacked = jax.lax.all_gather(x, axis_name, axis=axis)
-        merged = stacked.shape[axis] * stacked.shape[axis + 1]
-        return stacked.reshape(*x.shape[:axis], merged, *x.shape[axis + 1:])
-
-
-def _flat_axis_index(axis_name):
-    """Row-major flat index over one axis name or a tuple of names."""
-    names = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
-    idx = 0
-    for name in names:
-        idx = idx * jax.lax.psum(1, name) + jax.lax.axis_index(name)
-    return idx
-
-
-# ---------------------------------------------------------------------------
-# Differentiable optimization_barrier
-# ---------------------------------------------------------------------------
-
-def _probe_barrier_grad() -> bool:
-    try:
-        jax.eval_shape(
-            jax.grad(lambda x: jax.lax.optimization_barrier(x * 1.0)),
-            jax.ShapeDtypeStruct((), jnp.float32))
-        return True
-    except NotImplementedError:
-        return False
-    except Exception:
-        return False
-
-
-BARRIER_IS_DIFFERENTIABLE = _probe_barrier_grad()
-
-
-@jax.custom_vjp
-def _barrier_vjp(x):
-    return jax.lax.optimization_barrier(x)
-
-
-def _barrier_fwd(x):
-    return _barrier_vjp(x), None
-
-
-def _barrier_bwd(_, ct):
-    # Barrier the cotangent too: the reverse pass wants the same
-    # hoisting protection (e.g. keeping f32 upcasts loop-local) as the
-    # primal that requested the barrier.
-    return (jax.lax.optimization_barrier(ct),)
-
-
-_barrier_vjp.defvjp(_barrier_fwd, _barrier_bwd)
-
-
-def optimization_barrier(x):
-    """Identity that blocks XLA hoisting; differentiable on every JAX."""
-    if BARRIER_IS_DIFFERENTIABLE:
-        return jax.lax.optimization_barrier(x)
-    return _barrier_vjp(x)
+    return jax.lax.all_gather(x, axis_name, axis=axis, tiled=True)
 
 
 # ---------------------------------------------------------------------------

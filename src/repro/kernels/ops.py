@@ -6,9 +6,10 @@ run under the table's bucket-local fitted spec when it has one; explicit
 per-call block/``splits=`` kwargs beat both, and ``GemmPolicy.split`` pins
 S scope-wide), padding to block multiples (zero-padding is exact for GEMM;
 split paths pad the reduction to whole S-slices), interpret-mode
-resolution (policy field; auto-detect runs kernel bodies in Python on CPU
-and compiles via Mosaic on TPU), and lane-dim padding of skinny minor dims
-when lowering for real TPUs. Split (S > 1) dispatch runs the
+resolution (``compat.auto_interpret`` of the policy field: kernel bodies run
+in Python off-TPU and compile via Mosaic on a TPU), and the scoped-VMEM
+limit each launch hands Mosaic -- the budget of the spec the block chooser
+ran under (``contracts.vmem_limit_bytes``). Split (S > 1) dispatch runs the
 ``*_pallas_split`` kernel and sums the (S, ...) f32 partials through
 ``repro.kernels.reduce.reduce_partials`` before slicing off the padding,
 so callers see the exact sequential-kernel contract.
@@ -116,11 +117,6 @@ def _effective_policy(policy, spec, interpret):
     return dataclasses.replace(p, **repl) if repl else p
 
 
-def _resolve_interpret(policy) -> bool:
-    return (compat.auto_interpret() if policy.interpret is None
-            else policy.interpret)
-
-
 def _tuned_params(policy, kind, dims, dtype, interpret) -> dict | None:
     """Measured-best block params from ``policy.tuning_table``, if any.
 
@@ -164,8 +160,12 @@ def _policy_split(policy) -> int | None:
     return int(s)
 
 
-def _vmem_budget(policy) -> int:
-    return int(policy.spec.vmem_bytes * policy.spec.vmem_usable)
+def _vmem_limit(policy, kind, dims, dtype) -> int:
+    """Scoped-VMEM limit of one launch: the budget of the very spec the
+    block chooser ran under (``_analytic_spec``), so the compiler enforces
+    what the model sized against."""
+    return contracts.vmem_limit_bytes(_analytic_spec(policy, kind, dims,
+                                                     dtype))
 
 
 def _note_launch(kind, padded_shape, params):
@@ -299,7 +299,7 @@ def resolve_params(kind: str, m: int, d1: int, d2: int, dtype, policy, *,
     caller didn't ask for must fail loudly.
     """
     if interpret is None:
-        interpret = _resolve_interpret(policy)
+        interpret = compat.auto_interpret(policy.interpret)
     quant = getattr(policy, "quant", "none") == "int8"
     eff_dtype = jnp.int8 if quant else dtype
     if kind == "tsm2r":
@@ -354,11 +354,13 @@ def resolve_params(kind: str, m: int, d1: int, d2: int, dtype, policy, *,
 def _tsm2r_impl(a, b, block_m, block_k, splits, policy):
     m, k = a.shape
     n = b.shape[1]
-    interpret = _resolve_interpret(policy)
+    interpret = compat.auto_interpret(policy.interpret)
     p = resolve_params("tsm2r", m, k, n, a.dtype, policy, block_m=block_m,
                        block_k=block_k, splits=splits, interpret=interpret)
     block_m, block_k, splits = p["block_m"], p["block_k"], p["splits"]
     quant = getattr(policy, "quant", "none") == "int8"
+    vmem = _vmem_limit(policy, "tsm2r", (m, k, n),
+                       jnp.int8 if quant else a.dtype)
     if splits == 1:
         a_p = _pad_to(_pad_to(a, 0, block_m), 1, block_k)
         b_p = _pad_to(b, 0, block_k)
@@ -368,10 +370,10 @@ def _tsm2r_impl(a, b, block_m, block_k, splits, policy):
             b_q, b_s = kquant.quantize_tensor(b_p)
             out = kquant.tsm2r_q8_pallas(
                 a_q, b_q, a_s, b_s, out_dtype=a.dtype, block_m=block_m,
-                block_k=block_k, interpret=interpret)
+                block_k=block_k, interpret=interpret, vmem_limit_bytes=vmem)
         else:
             out = tsm2r_pallas(a_p, b_p, block_m=block_m, block_k=block_k,
-                               interpret=interpret)
+                               interpret=interpret, vmem_limit_bytes=vmem)
         return out[:m]
     # Split reduction: pad k so every slice is whole (zero-padding is exact
     # for GEMM, so m % (S*bk) non-multiples cost only the padded stream).
@@ -383,18 +385,17 @@ def _tsm2r_impl(a, b, block_m, block_k, splits, policy):
         b_q, b_s = kquant.quantize_tensor(b_p)
         parts = kquant.tsm2r_q8_pallas_split(
             a_q, b_q, a_s, b_s, block_m=block_m, block_k=block_k,
-            splits=splits, interpret=interpret)
+            splits=splits, interpret=interpret, vmem_limit_bytes=vmem)
     else:
         parts = tsm2r_pallas_split(a_p, b_p, block_m=block_m,
                                    block_k=block_k, splits=splits,
-                                   interpret=interpret)
+                                   interpret=interpret, vmem_limit_bytes=vmem)
     br = epilogue_block_r(splits, a_p.shape[0], n, block_r=block_m,
-                          vmem_budget=_vmem_budget(policy))
+                          vmem_budget=vmem)
     if br is not None:
         _note_launch("reduce", (splits, a_p.shape[0], n), {"block_r": br})
     out = reduce_partials(parts, a.dtype, block_r=block_m,
-                          vmem_budget=_vmem_budget(policy),
-                          interpret=interpret)
+                          vmem_budget=vmem, interpret=interpret)
     return out[:m]
 
 
@@ -445,18 +446,23 @@ def tsm2r(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int | None = None,
 def _tsm2l_impl(a, b, block_m, policy):
     m, k = a.shape
     n = b.shape[1]
-    interpret = _resolve_interpret(policy)
+    interpret = compat.auto_interpret(policy.interpret)
     block_m = resolve_params("tsm2l", m, k, n, a.dtype, policy,
                              block_m=block_m, interpret=interpret)["block_m"]
     a_p = _pad_to(a, 0, block_m)
     _note_launch("tsm2l", (a_p.shape[0], k, n), {"block_m": block_m})
-    if getattr(policy, "quant", "none") == "int8":
+    quant = getattr(policy, "quant", "none") == "int8"
+    vmem = _vmem_limit(policy, "tsm2l", (m, k, n),
+                       jnp.int8 if quant else a.dtype)
+    if quant:
         a_q, a_s = kquant.quantize_blocks(a_p, block_m)
         b_q, b_s = kquant.quantize_tensor(b)
         out = kquant.tsm2l_q8_pallas(a_q, b_q, a_s, b_s, out_dtype=a.dtype,
-                                     block_m=block_m, interpret=interpret)
+                                     block_m=block_m, interpret=interpret,
+                                     vmem_limit_bytes=vmem)
     else:
-        out = tsm2l_pallas(a_p, b, block_m=block_m, interpret=interpret)
+        out = tsm2l_pallas(a_p, b, block_m=block_m, interpret=interpret,
+                           vmem_limit_bytes=vmem)
     return out[:m]
 
 
@@ -499,12 +505,14 @@ def tsm2l(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int | None = None,
 def _tsmt_impl(x, y, block_m, block_a, splits, policy):
     m, a_dim = x.shape
     b_dim = y.shape[1]
-    interpret = _resolve_interpret(policy)
+    interpret = compat.auto_interpret(policy.interpret)
     p = resolve_params("tsmt", m, a_dim, b_dim, x.dtype, policy,
                        block_m=block_m, block_a=block_a, splits=splits,
                        interpret=interpret)
     block_m, block_a, splits = p["block_m"], p["block_a"], p["splits"]
     quant = getattr(policy, "quant", "none") == "int8"
+    vmem = _vmem_limit(policy, "tsmt", (m, a_dim, b_dim),
+                       jnp.int8 if quant else x.dtype)
     if splits == 1:
         x_p = _pad_to(_pad_to(x, 0, block_m), 1, block_a)
         y_p = _pad_to(y, 0, block_m)
@@ -514,10 +522,10 @@ def _tsmt_impl(x, y, block_m, block_a, splits, policy):
             y_q, y_s = kquant.quantize_blocks(y_p, block_m)
             out = kquant.tsmt_q8_pallas(
                 x_q, y_q, x_s, y_s, out_dtype=x.dtype, block_m=block_m,
-                block_a=block_a, interpret=interpret)
+                block_a=block_a, interpret=interpret, vmem_limit_bytes=vmem)
         else:
             out = tsmt_pallas(x_p, y_p, block_m=block_m, block_a=block_a,
-                              interpret=interpret)
+                              interpret=interpret, vmem_limit_bytes=vmem)
         return out[:a_dim]
     # Split reduction over m: pad to whole slices (zeros contribute
     # nothing to the partial sums), reduce the (S, a, b) f32 stack.
@@ -529,19 +537,18 @@ def _tsmt_impl(x, y, block_m, block_a, splits, policy):
         y_q, y_s = kquant.quantize_blocks(y_p, block_m)
         parts = kquant.tsmt_q8_pallas_split(
             x_q, y_q, x_s, y_s, block_m=block_m, block_a=block_a,
-            splits=splits, interpret=interpret)
+            splits=splits, interpret=interpret, vmem_limit_bytes=vmem)
     else:
         parts = tsmt_pallas_split(x_p, y_p, block_m=block_m,
                                   block_a=block_a, splits=splits,
-                                  interpret=interpret)
+                                  interpret=interpret, vmem_limit_bytes=vmem)
     br = epilogue_block_r(splits, x_p.shape[1], b_dim, block_r=block_a,
-                          vmem_budget=_vmem_budget(policy))
+                          vmem_budget=vmem)
     if br is not None:
         _note_launch("reduce", (splits, x_p.shape[1], b_dim),
                      {"block_r": br})
     out = reduce_partials(parts, x.dtype, block_r=block_a,
-                          vmem_budget=_vmem_budget(policy),
-                          interpret=interpret)
+                          vmem_budget=vmem, interpret=interpret)
     return out[:a_dim]
 
 

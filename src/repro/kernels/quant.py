@@ -162,6 +162,15 @@ def has_quantized_weights(params) -> bool:
     return bool(found)
 
 
+def _band_scales(scale: jnp.ndarray) -> jnp.ndarray:
+    """``(blocks, 1)`` band sidecar as ``(blocks, 1, 1)`` for the launch:
+    each grid step reads a ``(1, 1, 1)`` block, whose two minor dims equal
+    the array's -- the only way Mosaic accepts a block this small (a
+    ``(1, 1)`` block of a ``(blocks, 1)`` array breaks its 8x128 tiling
+    rule)."""
+    return scale.reshape(scale.shape[0], 1, 1)
+
+
 # ---------------------------------------------------------------------------
 # Quantized TSM2R: C[m,n] = A @ B, A per-m-block scales, B per-tensor
 # ---------------------------------------------------------------------------
@@ -181,13 +190,20 @@ def _tsm2r_q8_kernel(a_ref, b_ref, as_ref, bs_ref, o_ref, acc_ref):
 
     @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
     def _flush():
-        o_ref[...] = (acc_ref[...] * (as_ref[0, 0] * bs_ref[0, 0])).astype(
+        o_ref[...] = (acc_ref[...] * (as_ref[0, 0, 0] * bs_ref[0, 0])).astype(
             o_ref.dtype
         )
 
 
 @functools.partial(
-    jax.jit, static_argnames=("out_dtype", "block_m", "block_k", "interpret")
+    jax.jit,
+    static_argnames=(
+        "out_dtype",
+        "block_m",
+        "block_k",
+        "interpret",
+        "vmem_limit_bytes",
+    ),
 )
 def tsm2r_q8_pallas(
     a: jnp.ndarray,
@@ -198,12 +214,11 @@ def tsm2r_q8_pallas(
     out_dtype,
     block_m: int,
     block_k: int,
-    interpret: bool | None = None,
+    interpret: bool,
+    vmem_limit_bytes: int,
 ) -> jnp.ndarray:
     """Quantized TSM2R. ``a``/``b`` int8, ``a_scale`` ``(m/bm, 1)`` f32
     (one band per grid row block), ``b_scale`` ``(1, 1)`` f32."""
-    if interpret is None:
-        interpret = compat.auto_interpret()
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
@@ -218,7 +233,7 @@ def tsm2r_q8_pallas(
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j: (i, j)),
             pl.BlockSpec((block_k, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_m, n), lambda i, j: (i, 0)),
@@ -226,9 +241,10 @@ def tsm2r_q8_pallas(
         scratch_shapes=[compat.VMEM((block_m, n), jnp.float32)],
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes,
         ),
         interpret=interpret,
-    )(a, b, a_scale, b_scale)
+    )(a, b, _band_scales(a_scale), b_scale)
 
 
 def _tsm2r_q8_split_kernel(a_ref, b_ref, as_ref, bs_ref, o_ref):
@@ -244,12 +260,19 @@ def _tsm2r_q8_split_kernel(a_ref, b_ref, as_ref, bs_ref, o_ref):
         jnp.dot(a_ref[...], b_ref[...], preferred_element_type=jnp.int32).astype(
             jnp.float32
         )
-        * (as_ref[0, 0] * bs_ref[0, 0])
+        * (as_ref[0, 0, 0] * bs_ref[0, 0])
     )[None]
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_m", "block_k", "splits", "interpret")
+    jax.jit,
+    static_argnames=(
+        "block_m",
+        "block_k",
+        "splits",
+        "interpret",
+        "vmem_limit_bytes",
+    ),
 )
 def tsm2r_q8_pallas_split(
     a: jnp.ndarray,
@@ -260,12 +283,11 @@ def tsm2r_q8_pallas_split(
     block_m: int,
     block_k: int,
     splits: int,
-    interpret: bool | None = None,
+    interpret: bool,
+    vmem_limit_bytes: int,
 ) -> jnp.ndarray:
     """Split-reduction quantized TSM2R: ``(splits, m, n)`` f32 partials,
     already dequantized -- sum with ``reduce.reduce_partials`` as usual."""
-    if interpret is None:
-        interpret = compat.auto_interpret()
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
@@ -287,16 +309,17 @@ def tsm2r_q8_pallas_split(
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda s, i, j: (i, s * steps + j)),
             pl.BlockSpec((block_k, n), lambda s, i, j: (s * steps + j, 0)),
-            pl.BlockSpec((1, 1), lambda s, i, j: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda s, i, j: (i, 0, 0)),
             pl.BlockSpec((1, 1), lambda s, i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_m, n), lambda s, i, j: (s, i, 0)),
         out_shape=jax.ShapeDtypeStruct((splits, m, n), jnp.float32),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes,
         ),
         interpret=interpret,
-    )(a, b, a_scale, b_scale)
+    )(a, b, _band_scales(a_scale), b_scale)
 
 
 # ---------------------------------------------------------------------------
@@ -310,11 +333,19 @@ def _tsm2l_q8_kernel(a_ref, b_ref, as_ref, bs_ref, o_ref):
         jnp.dot(a_ref[...], b_ref[...], preferred_element_type=jnp.int32).astype(
             jnp.float32
         )
-        * (as_ref[0, 0] * bs_ref[0, 0])
+        * (as_ref[0, 0, 0] * bs_ref[0, 0])
     ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("out_dtype", "block_m", "interpret"))
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "out_dtype",
+        "block_m",
+        "interpret",
+        "vmem_limit_bytes",
+    ),
+)
 def tsm2l_q8_pallas(
     a: jnp.ndarray,
     b: jnp.ndarray,
@@ -323,12 +354,11 @@ def tsm2l_q8_pallas(
     *,
     out_dtype,
     block_m: int,
-    interpret: bool | None = None,
+    interpret: bool,
+    vmem_limit_bytes: int,
 ) -> jnp.ndarray:
     """Quantized TSM2L. ``a_scale`` ``(m/bm, 1)`` f32, ``b_scale``
     ``(1, 1)`` f32; B stays VMEM-resident exactly as in the f32 kernel."""
-    if interpret is None:
-        interpret = compat.auto_interpret()
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
@@ -343,16 +373,17 @@ def tsm2l_q8_pallas(
         in_specs=[
             pl.BlockSpec((block_m, k), lambda i: (i, 0)),
             pl.BlockSpec((k, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_m, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit_bytes,
         ),
         interpret=interpret,
-    )(a, b, a_scale, b_scale)
+    )(a, b, _band_scales(a_scale), b_scale)
 
 
 # ---------------------------------------------------------------------------
@@ -374,7 +405,7 @@ def _tsmt_q8_kernel(x_ref, y_ref, xs_ref, ys_ref, o_ref, acc_ref):
         (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.int32,
     )
-    acc_ref[...] += dot.astype(jnp.float32) * (xs_ref[0, 0] * ys_ref[0, 0])
+    acc_ref[...] += dot.astype(jnp.float32) * (xs_ref[0, 0, 0] * ys_ref[0, 0, 0])
 
     @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
     def _flush():
@@ -382,7 +413,14 @@ def _tsmt_q8_kernel(x_ref, y_ref, xs_ref, ys_ref, o_ref, acc_ref):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("out_dtype", "block_m", "block_a", "interpret")
+    jax.jit,
+    static_argnames=(
+        "out_dtype",
+        "block_m",
+        "block_a",
+        "interpret",
+        "vmem_limit_bytes",
+    ),
 )
 def tsmt_q8_pallas(
     x: jnp.ndarray,
@@ -393,12 +431,11 @@ def tsmt_q8_pallas(
     out_dtype,
     block_m: int,
     block_a: int,
-    interpret: bool | None = None,
+    interpret: bool,
+    vmem_limit_bytes: int,
 ) -> jnp.ndarray:
     """Quantized TSMT. Both operands are tall, so both carry per-m-band
     ``(m/bm, 1)`` f32 sidecars indexed by the sequential grid dim."""
-    if interpret is None:
-        interpret = compat.auto_interpret()
     m, a = x.shape
     m2, b = y.shape
     assert m == m2, (x.shape, y.shape)
@@ -413,17 +450,18 @@ def tsmt_q8_pallas(
         in_specs=[
             pl.BlockSpec((block_m, block_a), lambda i, j: (j, i)),
             pl.BlockSpec((block_m, b), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, j: (j, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, j: (j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((block_a, b), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((a, b), out_dtype),
         scratch_shapes=[compat.VMEM((block_a, b), jnp.float32)],
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes,
         ),
         interpret=interpret,
-    )(x, y, x_scale, y_scale)
+    )(x, y, _band_scales(x_scale), _band_scales(y_scale))
 
 
 def _tsmt_q8_split_kernel(x_ref, y_ref, xs_ref, ys_ref, o_ref):
@@ -439,11 +477,18 @@ def _tsmt_q8_split_kernel(x_ref, y_ref, xs_ref, ys_ref, o_ref):
         (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.int32,
     )
-    o_ref[...] += (dot.astype(jnp.float32) * (xs_ref[0, 0] * ys_ref[0, 0]))[None]
+    o_ref[...] += (dot.astype(jnp.float32) * (xs_ref[0, 0, 0] * ys_ref[0, 0, 0]))[None]
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_m", "block_a", "splits", "interpret")
+    jax.jit,
+    static_argnames=(
+        "block_m",
+        "block_a",
+        "splits",
+        "interpret",
+        "vmem_limit_bytes",
+    ),
 )
 def tsmt_q8_pallas_split(
     x: jnp.ndarray,
@@ -454,12 +499,11 @@ def tsmt_q8_pallas_split(
     block_m: int,
     block_a: int,
     splits: int,
-    interpret: bool | None = None,
+    interpret: bool,
+    vmem_limit_bytes: int,
 ) -> jnp.ndarray:
     """Split-reduction quantized TSMT: ``(splits, a, b)`` f32 partials,
     dequantized in-kernel so the reduce/psum machinery is unchanged."""
-    if interpret is None:
-        interpret = compat.auto_interpret()
     m, a = x.shape
     m2, b = y.shape
     assert m == m2, (x.shape, y.shape)
@@ -481,13 +525,14 @@ def tsmt_q8_pallas_split(
         in_specs=[
             pl.BlockSpec((block_m, block_a), lambda s, i, j: (s * steps + j, i)),
             pl.BlockSpec((block_m, b), lambda s, i, j: (s * steps + j, 0)),
-            pl.BlockSpec((1, 1), lambda s, i, j: (s * steps + j, 0)),
-            pl.BlockSpec((1, 1), lambda s, i, j: (s * steps + j, 0)),
+            pl.BlockSpec((1, 1, 1), lambda s, i, j: (s * steps + j, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda s, i, j: (s * steps + j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_a, b), lambda s, i, j: (s, i, 0)),
         out_shape=jax.ShapeDtypeStruct((splits, a, b), jnp.float32),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes,
         ),
         interpret=interpret,
-    )(x, y, x_scale, y_scale)
+    )(x, y, _band_scales(x_scale), _band_scales(y_scale))

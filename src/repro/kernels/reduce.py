@@ -41,9 +41,11 @@ def _sum_lead_kernel(x_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "out_dtype",
-                                             "interpret"))
+                                             "interpret",
+                                             "vmem_limit_bytes"))
 def sum_partials_pallas(p: jnp.ndarray, *, block_r: int, out_dtype,
-                        interpret: bool | None = None) -> jnp.ndarray:
+                        interpret: bool,
+                        vmem_limit_bytes: int) -> jnp.ndarray:
     """Pallas sum over the leading axis of ``(S, rows, cols)``.
 
     Requires ``rows % block_r == 0`` (the split kernels' row axis is
@@ -51,8 +53,6 @@ def sum_partials_pallas(p: jnp.ndarray, *, block_r: int, out_dtype,
     resident per cell -- callers size ``block_r`` against VMEM
     (:func:`reduce_partials` does).
     """
-    if interpret is None:
-        interpret = compat.auto_interpret()
     s, rows, cols = p.shape
     assert rows % block_r == 0, (rows, block_r)
     return compat.pallas_call(
@@ -63,6 +63,7 @@ def sum_partials_pallas(p: jnp.ndarray, *, block_r: int, out_dtype,
         out_shape=jax.ShapeDtypeStruct((rows, cols), out_dtype),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel",),
+            vmem_limit_bytes=vmem_limit_bytes,
         ),
         interpret=interpret,
     )(p)
@@ -82,11 +83,12 @@ def epilogue_block_r(s: int, rows: int, cols: int, *, block_r: int,
     if s == 1 or s * rows * cols <= JNP_REDUCE_MAX_ELEMS:
         return None
     block_r = min(block_r, rows)
-    # in stack + out block, f32; lane-padded cols approximates the tile.
+    # Double-buffered in stack + out block, f32; lane-padded cols
+    # approximates the tile.
     cols_pad = ((cols + 127) // 128) * 128
 
     def cell_bytes(br):
-        return (s + 1) * br * cols_pad * 4
+        return 2 * (s + 1) * br * cols_pad * 4
 
     while cell_bytes(block_r) > vmem_budget and block_r % 2 == 0 and block_r > 8:
         block_r //= 2
@@ -96,14 +98,14 @@ def epilogue_block_r(s: int, rows: int, cols: int, *, block_r: int,
 
 
 def reduce_partials(p: jnp.ndarray, out_dtype, *, block_r: int,
-                    vmem_budget: int, interpret: bool | None = None
-                    ) -> jnp.ndarray:
+                    vmem_budget: int, interpret: bool) -> jnp.ndarray:
     """Sum the ``(S, rows, cols)`` partials stack to ``(rows, cols)``.
 
     ``block_r`` is the emitting kernel's row block (it divides rows by
     construction); :func:`epilogue_block_r` halves it while the per-cell
     stack would overrun ``vmem_budget`` bytes, or returns None to take the
-    fused ``jnp.sum`` path (small stacks, or no feasible block).
+    fused ``jnp.sum`` path (small stacks, or no feasible block). The same
+    ``vmem_budget`` is the kernel's scoped-VMEM limit.
     """
     s, rows, cols = p.shape
     if s == 1:
@@ -113,4 +115,5 @@ def reduce_partials(p: jnp.ndarray, out_dtype, *, block_r: int,
     if br is None:
         return jnp.sum(p.astype(jnp.float32), axis=0).astype(out_dtype)
     return sum_partials_pallas(p, block_r=br, out_dtype=out_dtype,
-                               interpret=interpret)
+                               interpret=interpret,
+                               vmem_limit_bytes=vmem_budget)

@@ -44,18 +44,21 @@ def _tsm2l_kernel(a_ref, b_ref, o_ref):
     ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_m", "interpret",
+                                             "vmem_limit_bytes"))
 def tsm2l_pallas(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int,
-                 interpret: bool | None = None) -> jnp.ndarray:
+                 interpret: bool,
+                 vmem_limit_bytes: int) -> jnp.ndarray:
     """Raw pallas_call; requires m % block_m == 0.
 
-    ``interpret=None`` auto-detects (Python bodies off-TPU). Use
+    ``interpret`` runs the body in Python (``compat.auto_interpret``
+    resolves it once, in ``kernels/ops``); ``vmem_limit_bytes`` is the
+    scoped-VMEM limit handed to Mosaic, the same budget the block chooser
+    sized the windows against (``analysis.contracts.vmem_limit_bytes``). Use
     ``repro.kernels.ops.tsm2l`` for the padded/dispatched public entry;
     the ``shard_map`` executor in ``repro.core.tsmm`` handles multi-chip
     meshes by invoking that entry per shard.
     """
-    if interpret is None:
-        interpret = compat.auto_interpret()
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
@@ -74,6 +77,7 @@ def tsm2l_pallas(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int,
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit_bytes,
         ),
         interpret=interpret,
     )(a, b)

@@ -17,8 +17,8 @@ staging + data prefetch):
   paper's ``n/t1`` B-reload factor; with k*n tiny this is noise (it is the
   term the paper also drops, Section 3.1.8 "minor inaccuracy").
 * The shared-memory bank-conflict analysis (paper Section 3.1.4) has no TPU
-  analogue; the corresponding layout decision here is lane-dim padding of n
-  to 128 (done by ``ops.tsm2r`` when lowering for real TPUs).
+  analogue; the corresponding layout fact is that XLA stores a skinny
+  (., n) array at 128 lanes in HBM, so n < 128 windows stream padded.
 
 Block sizes (bm, bk) come from ``repro.core.perf_model.choose_params_tsm2r``,
 the discrete Algorithm-5 analogue -- which also picks the split factor S for
@@ -58,19 +58,22 @@ def _tsm2r_kernel(a_ref, b_ref, o_ref, acc_ref):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "block_k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_m", "block_k", "interpret",
+                                             "vmem_limit_bytes"))
 def tsm2r_pallas(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int, block_k: int,
-                 interpret: bool | None = None) -> jnp.ndarray:
+                 interpret: bool,
+                 vmem_limit_bytes: int) -> jnp.ndarray:
     """Raw pallas_call; requires m % block_m == 0 and k % block_k == 0.
 
-    ``interpret=None`` auto-detects (Python bodies off-TPU). Use
+    ``interpret`` runs the body in Python (``compat.auto_interpret``
+    resolves it once, in ``kernels/ops``); ``vmem_limit_bytes`` is the
+    scoped-VMEM limit handed to Mosaic, the same budget the block chooser
+    sized the windows against (``analysis.contracts.vmem_limit_bytes``). Use
     ``repro.kernels.ops.tsm2r`` for the padded/dispatched public entry;
     under a multi-chip mesh the ``shard_map`` executor in
     ``repro.core.tsmm`` invokes that entry per shard (this call has no
     GSPMD partitioning rule of its own).
     """
-    if interpret is None:
-        interpret = compat.auto_interpret()
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
@@ -89,6 +92,7 @@ def tsm2r_pallas(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int, block_k: int,
         scratch_shapes=[compat.VMEM((block_m, n), jnp.float32)],
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes,
         ),
         interpret=interpret,
     )(a, b)
@@ -108,10 +112,12 @@ def _tsm2r_split_kernel(a_ref, b_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_k", "splits",
-                                             "interpret"))
+                                             "interpret",
+                                             "vmem_limit_bytes"))
 def tsm2r_pallas_split(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int,
                        block_k: int, splits: int,
-                       interpret: bool | None = None) -> jnp.ndarray:
+                       interpret: bool,
+                       vmem_limit_bytes: int) -> jnp.ndarray:
     """Split-reduction TSM2R: returns the ``(splits, m, n)`` f32 partials.
 
     Requires ``m % block_m == 0`` and ``k % (splits * block_k) == 0``
@@ -119,8 +125,6 @@ def tsm2r_pallas_split(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int,
     parallel, each sweeps its own k range sequentially. Callers sum the
     leading axis (``repro.kernels.reduce.reduce_partials``).
     """
-    if interpret is None:
-        interpret = compat.auto_interpret()
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
@@ -141,6 +145,7 @@ def tsm2r_pallas_split(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int,
         out_shape=jax.ShapeDtypeStruct((splits, m, n), jnp.float32),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes,
         ),
         interpret=interpret,
     )(a, b)

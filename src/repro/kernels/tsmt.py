@@ -59,18 +59,21 @@ def _tsmt_kernel(x_ref, y_ref, o_ref, acc_ref):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "block_a", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_m", "block_a", "interpret",
+                                             "vmem_limit_bytes"))
 def tsmt_pallas(x: jnp.ndarray, y: jnp.ndarray, *, block_m: int, block_a: int,
-                interpret: bool | None = None) -> jnp.ndarray:
+                interpret: bool,
+                vmem_limit_bytes: int) -> jnp.ndarray:
     """Raw pallas_call; requires m % block_m == 0 and a % block_a == 0.
 
-    ``interpret=None`` auto-detects (Python bodies off-TPU). Use
+    ``interpret`` runs the body in Python (``compat.auto_interpret``
+    resolves it once, in ``kernels/ops``); ``vmem_limit_bytes`` is the
+    scoped-VMEM limit handed to Mosaic, the same budget the block chooser
+    sized the windows against (``analysis.contracts.vmem_limit_bytes``). Use
     ``repro.kernels.ops.tsmt`` for the padded/dispatched public entry;
     under a multi-chip mesh the ``shard_map`` executor in
     ``repro.core.tsmm`` runs that entry per shard and psums the partials.
     """
-    if interpret is None:
-        interpret = compat.auto_interpret()
     m, a = x.shape
     m2, b = y.shape
     assert m == m2, (x.shape, y.shape)
@@ -89,6 +92,7 @@ def tsmt_pallas(x: jnp.ndarray, y: jnp.ndarray, *, block_m: int, block_a: int,
         scratch_shapes=[compat.VMEM((block_a, b), jnp.float32)],
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes,
         ),
         interpret=interpret,
     )(x, y)
@@ -111,10 +115,12 @@ def _tsmt_split_kernel(x_ref, y_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_a", "splits",
-                                             "interpret"))
+                                             "interpret",
+                                             "vmem_limit_bytes"))
 def tsmt_pallas_split(x: jnp.ndarray, y: jnp.ndarray, *, block_m: int,
                       block_a: int, splits: int,
-                      interpret: bool | None = None) -> jnp.ndarray:
+                      interpret: bool,
+                      vmem_limit_bytes: int) -> jnp.ndarray:
     """Split-reduction TSMT: returns the ``(splits, a, b)`` f32 partials.
 
     Requires ``m % (splits * block_m) == 0`` and ``a % block_a == 0``
@@ -123,8 +129,6 @@ def tsmt_pallas_split(x: jnp.ndarray, y: jnp.ndarray, *, block_m: int,
     slice's m blocks sequentially. Callers sum the leading axis
     (``repro.kernels.reduce.reduce_partials``).
     """
-    if interpret is None:
-        interpret = compat.auto_interpret()
     m, a = x.shape
     m2, b = y.shape
     assert m == m2, (x.shape, y.shape)
@@ -145,6 +149,7 @@ def tsmt_pallas_split(x: jnp.ndarray, y: jnp.ndarray, *, block_m: int,
         out_shape=jax.ShapeDtypeStruct((splits, a, b), jnp.float32),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes,
         ),
         interpret=interpret,
     )(x, y)

@@ -1,12 +1,16 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("XLA_FLAGS", ""))
 
 """Multi-pod dry run: AOT lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST stay first: jax locks the device count at first
+The lines above MUST stay first: jax locks the device count at first
 initialization, and the production meshes need 512 placeholder host
-devices. Smoke tests / benchmarks never import this module and keep 1
+devices. The dry run is a CPU tool: ``JAX_PLATFORMS=cpu`` keeps this
+process and the per-cell children of ``--all`` (which inherit the
+environment) off any accelerator, so no child waits on a chip the parent
+holds. Smoke tests / benchmarks never import this module and keep 1
 device.
 
 Per cell this produces (artifacts/dryrun/<arch>__<shape>__<mesh>.json):
@@ -129,7 +133,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
         step_fn = ts.make_train_step(cfg, opt_cfg, n_micro=n_micro,
                                      acc_shardings=p_named, mesh=mesh,
                                      opt_update_specs=upd_specs)
-        with mesh:
+        with jax.set_mesh(mesh):
             # donate the train state: params/opt buffers alias in-place
             lowered = jax.jit(step_fn,
                               in_shardings=(state_named, b_named),
@@ -145,7 +149,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
             def encode_step(params, batch):
                 return model.forward(params, cfg, batch)
 
-            with mesh:
+            with jax.set_mesh(mesh):
                 lowered = jax.jit(encode_step,
                                   in_shardings=(p_named, b_named)
                                   ).lower(params_shape, batch_shape)
@@ -158,7 +162,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
             def prefill_step(params, batch, cache):
                 return model.prefill(params, cfg, batch, cache)
 
-            with mesh:
+            with jax.set_mesh(mesh):
                 lowered = jax.jit(prefill_step,
                                   in_shardings=(p_named, b_named, c_named),
                                   out_shardings=(None, c_named)
@@ -175,7 +179,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
         def decode_step(params, tokens, pos, cache):
             return model.decode_step(params, cfg, tokens, pos, cache)
 
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(decode_step,
                               in_shardings=(p_named, t_named, None, c_named),
                               out_shardings=(None, c_named)

@@ -22,8 +22,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     return compat.make_mesh(shape, axes)
 
 
-def make_host_mesh(model: int = 1):
-    """Tiny mesh over however many devices this host exposes (tests)."""
-    n = len(jax.devices())
+def make_host_mesh(model: int = 1, devices=None):
+    """("data", "model") mesh over this host's devices (default: all)."""
+    devices = list(jax.devices() if devices is None else devices)
+    n = len(devices)
     assert n % model == 0
-    return compat.make_mesh((n // model, model), ("data", "model"))
+    return compat.make_mesh((n // model, model), ("data", "model"),
+                            devices=devices)

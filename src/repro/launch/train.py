@@ -19,8 +19,9 @@ exercise exactly this path (see tests/test_train_rollback.py).
 
 On real TPU pods: run under `jax.distributed.initialize()` (flag
 --distributed), one process per host; the mesh comes from launch/mesh.py
-and XLA latency-hiding flags are set below. On this CPU container the same
-code path runs with the host mesh (--smoke uses reduced configs).
+and XLA latency-hiding flags are set below. On one host the same code path
+runs with the host mesh (``--devices N`` limits it to the first N devices;
+--smoke uses reduced configs).
 """
 
 from __future__ import annotations
@@ -49,7 +50,7 @@ def main(argv=None):
     ap.add_argument("--lr", type=float, default=3e-3)
     ap.add_argument("--warmup", type=int, default=20)
     ap.add_argument("--smoke", action="store_true",
-                    help="use the reduced per-arch config (CPU-runnable)")
+                    help="use the reduced per-arch config")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--abft-every", type=int, default=0,
@@ -69,6 +70,9 @@ def main(argv=None):
     ap.add_argument("--powersgd-rank", type=int, default=0,
                     help="gradient compression rank (0=off)")
     ap.add_argument("--model-axis", type=int, default=1)
+    ap.add_argument("--devices", type=int, default=0,
+                    help="build the mesh over the first N devices "
+                         "(0 = all of this host's)")
     ap.add_argument("--distributed", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
@@ -81,6 +85,9 @@ def main(argv=None):
     import jax
     import jax.numpy as jnp
 
+    from repro.launch.cache import configure_compilation_cache
+    configure_compilation_cache()
+
     from repro.checkpoint.checkpointer import Checkpointer
     from repro.configs import registry
     from repro.core import tsmm
@@ -92,7 +99,9 @@ def main(argv=None):
     from repro.train import train_step as ts
 
     cfg = registry.get_config(args.arch, smoke=args.smoke)
-    mesh = make_host_mesh(model=args.model_axis)
+    mesh = make_host_mesh(model=args.model_axis,
+                          devices=(jax.devices()[:args.devices]
+                                   if args.devices else None))
     host_index = jax.process_index()
     host_count = jax.process_count()
 
@@ -149,7 +158,7 @@ def main(argv=None):
         print(f"[train] restored checkpoint at step {start_step}")
         start_step += 1
     else:
-        with mesh:
+        with jax.set_mesh(mesh):
             state = jax.jit(
                 lambda k: ts.init_train_state(k, cfg, opt_cfg, extra=extra),
                 out_shardings=(state_named if extra is None else None),
@@ -174,6 +183,7 @@ def main(argv=None):
     total_retries = 0
     chaos_pending = args.chaos_step >= 0
     last_metrics = {}
+    losses = []                       # loss of every good step, in order
 
     abft_scope = (tsmm.policy(abft=args.abft) if args.abft != "none"
                   else contextlib.nullcontext())
@@ -196,7 +206,7 @@ def main(argv=None):
                     chaos_pending = False
                     print(f"[chaos] poisoned state before step {step}")
                 with wd:
-                    with mesh:
+                    with jax.set_mesh(mesh):
                         state, metrics = step_fn(state, batch)
                     step_ok = bool(metrics["step_ok"])
                 if not step_ok:
@@ -227,6 +237,7 @@ def main(argv=None):
                 # -- good step ------------------------------------------
                 retries_left = args.max_step_retries
                 last_metrics = metrics
+                losses.append(float(metrics["loss"]))
                 wm = wd.last_metrics
                 if args.snapshot_every and step % args.snapshot_every == 0:
                     snap = (step, ts.host_snapshot(state))
@@ -269,6 +280,7 @@ def main(argv=None):
           f"({steps_run / max(dt, 1e-9):.2f} steps/s); "
           f"fault retries: {total_retries}")
     return {"final_loss": float(last_metrics.get("loss", float("nan"))),
+            "losses": losses,
             "final_step": args.steps - 1,
             "fault_retries": total_retries,
             "fault_events": wd.fault_events}

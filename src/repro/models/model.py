@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.kernels import compat
 from repro.models import attention, blocks, layers
 
 
@@ -191,9 +190,7 @@ def _scan_layers_remat(cfg, seg_p, x, kind, n: int):
         # it XLA hoists convert(saved_stack) out of the backward while-loop,
         # materializing an f32 copy of ALL layer saves at once (21 GiB for
         # llama3.2-3b train_4k -- measured via buffer assignment).
-        # compat wraps it in a custom_vjp identity on JAX versions where
-        # the primitive has no differentiation rule.
-        h = compat.optimization_barrier(h)
+        h = lax.optimization_barrier(h)
         out, met = blocks.block_fwd(lp, h, cfg, kind)
         return out, met
 

@@ -201,7 +201,7 @@ def test_mesh_arm_per_shard_guard():
     m = 2048 * len(devs)
     x, y = _operands("tsmt", (m, 64, 8))
     mesh = Mesh(np.array(devs), ("data",))
-    with mesh, tsmm.policy(interpret=True, reduce="psum", abft="verify"):
+    with jax.set_mesh(mesh), tsmm.policy(interpret=True, reduce="psum", abft="verify"):
         clean = np.asarray(tsmm.tsmm_t(x, y))
     with tsmm.policy(interpret=True):
         oracle = np.asarray(tsmm.tsmm_t(x, y))
@@ -211,7 +211,7 @@ def test_mesh_arm_per_shard_guard():
     # Site 1 is the first per-shard re-dispatch (site 0 = outer shard_map
     # invocation at the registry boundary).
     f = inject.GemmFault(site=1, operand="out", row=0, col=0, bit=29)
-    with mesh, tsmm.policy(interpret=True, reduce="psum", abft="verify"):
+    with jax.set_mesh(mesh), tsmm.policy(interpret=True, reduce="psum", abft="verify"):
         with inject.faults(f):
             out = np.asarray(tsmm.tsmm_t(x, y))
     assert np.isnan(out).any()

@@ -111,10 +111,11 @@ def tsm2r_spy(monkeypatch):
     seen = []
     orig = ops.tsm2r_pallas
 
-    def spy(a, b, *, block_m, block_k, interpret=None):
+    def spy(a, b, *, block_m, block_k, interpret, vmem_limit_bytes):
         seen.append({"block_m": block_m, "block_k": block_k})
         return orig(a, b, block_m=block_m, block_k=block_k,
-                    interpret=interpret)
+                    interpret=interpret,
+                    vmem_limit_bytes=vmem_limit_bytes)
 
     monkeypatch.setattr(ops, "tsm2r_pallas", spy)
     return seen

@@ -118,12 +118,17 @@ def test_finite_difference_directional():
     assert tsmm.classify_gemm(m, k, n) == "tsm2l"
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(2), 3)
     a, b = _rand(k1, (m, k)), _rand(k2, (k, n))
-    da_dir = _rand(k3, (m, k)) / m  # keep the perturbation small
+    # A unit-scale direction with a step sized for f32: the central
+    # difference's truncation error is O(eps^2), and the loss change
+    # (~eps * |grad|) stays far above the f32 rounding of the summed loss.
+    # (A direction scaled down by m moved each entry by ~40 ulps, so
+    # rounding alone put the quotient percents off.)
+    da_dir = _rand(k3, (m, k))
 
     def loss(a_):
         return jnp.sum(jnp.tanh(tsmm.tsmm(a_, b, interpret=True)))
 
-    eps = 1e-2
+    eps = 1e-3
     fd = (loss(a + eps * da_dir) - loss(a - eps * da_dir)) / (2 * eps)
     analytic = jnp.vdot(jax.grad(loss)(a), da_dir)
     np.testing.assert_allclose(float(fd), float(analytic), rtol=1e-2)

@@ -347,7 +347,7 @@ def test_capture_empty_reported(monkeypatch):
     reported as capture-empty, not silently passed."""
     from repro.kernels import tsm2l
 
-    def raw_entry(a, b, *, block_m, interpret=None):
+    def raw_entry(a, b, *, block_m, interpret, vmem_limit_bytes):
         return jnp.zeros((a.shape[0], b.shape[1]), a.dtype)
 
     monkeypatch.setattr(tsm2l, "tsm2l_pallas", raw_entry)

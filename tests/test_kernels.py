@@ -70,10 +70,11 @@ def test_tsm2r_block_quantization_matches_model(monkeypatch):
     seen = {}
     orig = ops.tsm2r_pallas
 
-    def spy(a, b, *, block_m, block_k, interpret=None):
+    def spy(a, b, *, block_m, block_k, interpret, vmem_limit_bytes):
         seen.update(block_m=block_m, block_k=block_k)
         return orig(a, b, block_m=block_m, block_k=block_k,
-                    interpret=interpret)
+                    interpret=interpret,
+                    vmem_limit_bytes=vmem_limit_bytes)
 
     monkeypatch.setattr(ops, "tsm2r_pallas", spy)
     m, k, n = 4096, 130, 8
@@ -92,10 +93,11 @@ def test_tsmt_block_quantization_matches_model(monkeypatch):
     seen = {}
     orig = ops.tsmt_pallas
 
-    def spy(x, y, *, block_m, block_a, interpret=None):
+    def spy(x, y, *, block_m, block_a, interpret, vmem_limit_bytes):
         seen.update(block_m=block_m, block_a=block_a)
         return orig(x, y, block_m=block_m, block_a=block_a,
-                    interpret=interpret)
+                    interpret=interpret,
+                    vmem_limit_bytes=vmem_limit_bytes)
 
     monkeypatch.setattr(ops, "tsmt_pallas", spy)
     m, a_dim, b_dim = 4096, 130, 8

@@ -70,7 +70,7 @@ def run_tree(a, reduce_):
         in_specs=(P("data", None),),
         out_specs=(P("data", None), P(None, None)),
     )
-    with mesh:
+    with jax.set_mesh(mesh):
         return jax.jit(f)(a)
 
 
@@ -126,7 +126,7 @@ def body_size1(a_loc):
     return linalg.tree_tsqr(a_loc, axis="model")
 
 
-with mesh1:
+with jax.set_mesh(mesh1):
     q1, r1 = jax.jit(
         compat.shard_map(
             body_size1,

@@ -164,3 +164,50 @@ def test_split_partials_traffic_is_priced():
     t4 = perf_model.tsm2r_model_time(2048, 2048, 8, 256, 128,
                                      perf_model.V5P, jnp.bfloat16, splits=4)
     assert t4 >= t1
+
+
+# ---------------------------------------------------------------------------
+# Spec lookup by device_kind
+# ---------------------------------------------------------------------------
+
+def _device(platform, kind):
+    import types
+    return types.SimpleNamespace(platform=platform, device_kind=kind)
+
+
+@pytest.mark.parametrize("kind,spec", [
+    ("TPU v5 lite", perf_model.V5E),     # what a v5e reports
+    ("TPU v5e", perf_model.V5E),
+    ("TPU v5", perf_model.V5P),          # what a v5p reports
+    ("TPU v5p", perf_model.V5P),
+])
+def test_device_spec_from_device_kind(kind, spec):
+    assert perf_model.device_spec(_device("tpu", kind)) is spec
+
+
+def test_device_spec_unknown_tpu_kind_raises():
+    with pytest.raises(ValueError, match="TPU v9 mega"):
+        perf_model.device_spec(_device("tpu", "TPU v9 mega"))
+
+
+def test_device_spec_off_tpu_keeps_modelled_default():
+    assert perf_model.device_spec(_device("cpu", "cpu")) is perf_model.V5E
+    # the CPU test backend: the default policy models the v5e
+    from repro.core import tsmm
+    assert perf_model.device_spec() is perf_model.V5E
+    assert tsmm.GemmPolicy().spec is perf_model.V5E
+
+
+def test_policy_spec_follows_the_device(monkeypatch):
+    """GemmPolicy(spec=None) resolves through device_spec at construction,
+    so an unknown TPU fails loudly instead of tuning for a v5e."""
+    from repro.core import tsmm
+    monkeypatch.setattr(perf_model.jax, "devices",
+                        lambda: [_device("tpu", "TPU v5")])
+    assert tsmm.GemmPolicy().spec is perf_model.V5P
+    monkeypatch.setattr(perf_model.jax, "devices",
+                        lambda: [_device("tpu", "TPU v9 mega")])
+    with pytest.raises(ValueError, match="no TPUSpec"):
+        tsmm.GemmPolicy()
+    # an explicit spec never consults the device
+    assert tsmm.GemmPolicy(spec=perf_model.V5E).spec is perf_model.V5E

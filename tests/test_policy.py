@@ -377,10 +377,61 @@ def test_executor_pin_collective_mismatch_raises():
     x = jnp.ones((4096, 64), jnp.float32)
     y = jnp.ones((4096, 8), jnp.float32)
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-    with mesh:
+    with jax.set_mesh(mesh):
         with tsmm.policy(executor="shard_map", reduce="psum_scatter"):
             with pytest.raises(RuntimeError, match="shard_map-scatter"):
                 tsmm.tsmm_t(x, y)
         with tsmm.policy(executor="shard_map-scatter"):  # default psum
             with pytest.raises(RuntimeError, match="psum_scatter"):
                 tsmm.tsmm_t(x, y)
+
+
+def test_context_mesh_follows_set_mesh():
+    """compat.get_context_mesh reads the jax.set_mesh scope (eagerly and
+    under jit) and reports no mesh inside a shard_map body, where the
+    dispatcher must treat shapes as local."""
+    from jax.sharding import PartitionSpec as P
+
+    mesh = compat.make_mesh((1,), ("data",))
+    assert compat.get_context_mesh() is None
+    seen = {}
+
+    def body(x):
+        seen["body"] = compat.get_context_mesh()
+        return x
+
+    def traced(x):
+        seen["jit"] = compat.get_context_mesh()
+        return compat.shard_map(body, mesh=mesh, in_specs=P("data"),
+                                out_specs=P("data"))(x)
+
+    with jax.set_mesh(mesh):
+        eager = compat.get_context_mesh()
+        jax.jit(traced)(jnp.ones((4,)))
+    assert eager is not None and eager.axis_names == ("data",)
+    assert compat.mesh_axis_sizes(eager) == {"data": 1}
+    assert seen["jit"] is not None and seen["body"] is None
+    assert compat.get_context_mesh() is None
+
+
+def test_repo_imports_raise_no_deprecation_warning():
+    """Every module of the package imports cleanly with JAX's deprecation
+    warnings turned into errors (no deprecated JAX spellings remain)."""
+    import pathlib
+    import subprocess
+    import sys
+
+    src = pathlib.Path(__file__).resolve().parents[1] / "src"
+    mods = sorted(
+        ".".join(p.relative_to(src).with_suffix("").parts)
+        for p in (src / "repro").rglob("*.py")
+        # dryrun rewrites XLA_FLAGS/JAX_PLATFORMS as it is imported
+        if p.name != "__init__.py" and p.name != "dryrun.py")
+    code = "import importlib\n" + "".join(
+        f"importlib.import_module({m!r})\n" for m in mods)
+    r = subprocess.run(
+        [sys.executable, "-W", "error::DeprecationWarning", "-c", code],
+        env={"PYTHONPATH": str(src), "JAX_PLATFORMS": "cpu",
+             "PATH": "/usr/bin:/bin"},
+        capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]

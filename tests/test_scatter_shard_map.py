@@ -53,7 +53,7 @@ x = jax.random.normal(jax.random.PRNGKey(2), (8192, 64), jnp.float32)
 y = jax.random.normal(jax.random.PRNGKey(3), (8192, 8), jnp.float32)
 
 # --- scatter executor: sharded output, oracle values ---------------------
-with mesh:
+with jax.set_mesh(mesh):
     with tsmm.policy(reduce="psum_scatter"):
         with tsmm.record_dispatches() as log:
             q = jax.jit(lambda x_, y_: tsmm.tsmm_t(x_, y_))(x, y)
@@ -70,7 +70,7 @@ np.testing.assert_allclose(np.asarray(q), np.asarray(x.T @ y),
 
 # --- scatter axis doesn't divide: dense fallback / require raises --------
 x63 = x[:, :63]
-with mesh:
+with jax.set_mesh(mesh):
     with tsmm.policy(reduce="psum_scatter"):
         with tsmm.record_dispatches() as log:
             jax.jit(lambda x_, y_: tsmm.tsmm_t(x_, y_))(x63, y)
@@ -84,7 +84,7 @@ with mesh:
             raise AssertionError("require + indivisible scatter did not raise")
 
 # --- psum default is untouched: replicated output ------------------------
-with mesh:
+with jax.set_mesh(mesh):
     with tsmm.record_dispatches() as log:
         q_rep = jax.jit(lambda x_, y_: tsmm.tsmm_t(x_, y_))(x, y)
 assert ("mmt", "tsmt", "shard_map") in {
@@ -96,7 +96,7 @@ assert {s.data.shape for s in q_rep.addressable_shards} == {(64, 8)}, "not repli
 w = jax.random.normal(jax.random.PRNGKey(4), (256, 8), jnp.float32)
 xs = jax.random.normal(jax.random.PRNGKey(5), (8192, 256), jnp.float32)
 pol = tsmm.GemmPolicy(reduce="psum_scatter", param_dtype_grads=True)
-with mesh:
+with jax.set_mesh(mesh):
     with tsmm.policy(pol):
         with tsmm.record_dispatches() as log:
             g = jax.jit(jax.grad(lambda w_, x_: jnp.sum(layers.dense(w_, x_))))
@@ -131,7 +131,7 @@ f = compat.shard_map(
     in_specs=(P("data", None, None),),
     out_specs=(P(None, None), P("data", None)),
 )
-with mesh:
+with jax.set_mesh(mesh):
     approx_s, q_s = jax.jit(f)(grads)
 np.testing.assert_allclose(np.asarray(approx_s), np.asarray(approx_o),
                            rtol=1e-4, atol=1e-4)
@@ -162,7 +162,7 @@ f_qr = compat.shard_map(
     in_specs=(P("data", None, None),),
     out_specs=(P(None, None), P("data", None)),
 )
-with mesh:
+with jax.set_mesh(mesh):
     with tsmm.record_dispatches() as log:
         approx_sq, q_sq = jax.jit(f_qr)(grads)
 np.testing.assert_allclose(np.asarray(approx_sq), np.asarray(approx_oq),
@@ -195,7 +195,7 @@ f_i8 = compat.shard_map(
     in_specs=(P("data", None, None),),
     out_specs=(P(None, None), P("data", None)),
 )
-with mesh:
+with jax.set_mesh(mesh):
     approx_si, q_si = jax.jit(f_i8)(grads)
 tol_a = 2e-2 * np.abs(np.asarray(approx_oi)).max()
 assert np.abs(np.asarray(approx_si) - np.asarray(approx_oi)).max() <= tol_a
@@ -218,7 +218,7 @@ for reduce_, expect_exec, expect_shard in (
     ("psum", "shard_map", (64, 8)),
     ("psum_scatter", "shard_map-scatter", (32, 8)),
 ):
-    with mesh:
+    with jax.set_mesh(mesh):
         with tsmm.policy(reduce=reduce_, split=2):
             with tsmm.record_dispatches() as log:
                 q_split = jax.jit(lambda x_, y_: tsmm.tsmm_t(x_, y_))(x, y)
@@ -235,13 +235,13 @@ for reduce_, expect_exec, expect_shard in (
 # --- dp_axes derived from an unconventionally named mesh -----------------
 mesh_r = Mesh(np.array(devs), ("replica",))
 assert tsmm.derive_dp_axes(mesh_r) == ("replica",)
-with mesh_r:
+with jax.set_mesh(mesh_r):
     with tsmm.policy(reduce="psum_scatter"):
         with tsmm.record_dispatches() as log:
             jax.jit(lambda x_, y_: tsmm.tsmm_t(x_, y_))(x, y)
 assert "shard_map-scatter" in {e.executor for e in log}, log
 # explicit override still wins: dp_axes naming no axis on the mesh -> no DP
-with mesh_r:
+with jax.set_mesh(mesh_r):
     with tsmm.policy(reduce="psum_scatter", dp_axes=("data",)):
         with tsmm.record_dispatches() as log:
             jax.jit(lambda x_, y_: tsmm.tsmm_t(x_, y_))(x, y)

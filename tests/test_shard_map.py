@@ -35,7 +35,7 @@ b = jax.random.normal(jax.random.PRNGKey(1), (2048, 8), jnp.float32)
 dense = jax.jit(lambda a_, b_: tsmm.tsmm(a_, b_, mode="dense"))(a, b)
 
 # --- auto-routing under the mesh: shard_map -> per-shard pallas kernel ---
-with mesh:
+with jax.set_mesh(mesh):
     with tsmm.record_dispatches() as log:
         f = jax.jit(lambda a_, b_: tsmm.tsmm(a_, b_))
         out = f(a, b)
@@ -50,7 +50,7 @@ np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
 # TSM2L shape: per-shard Abar is TSM2L again, Bbar the TSMTTSM shape.
 al = jax.random.normal(jax.random.PRNGKey(4), (8192, 16), jnp.float32)
 bl = jax.random.normal(jax.random.PRNGKey(5), (16, 8), jnp.float32)
-with mesh:
+with jax.set_mesh(mesh):
     with tsmm.record_dispatches() as log:
         g = jax.jit(jax.grad(lambda a_, b_: jnp.sum(tsmm.tsmm(a_, b_)),
                              (0, 1)))
@@ -67,7 +67,7 @@ np.testing.assert_allclose(np.asarray(db), np.asarray(rdb), rtol=2e-3,
 # --- tsmm_t: per-shard partials psum to the replicated product ----------
 x = jax.random.normal(jax.random.PRNGKey(2), (8192, 32), jnp.float32)
 y = jax.random.normal(jax.random.PRNGKey(3), (8192, 8), jnp.float32)
-with mesh:
+with jax.set_mesh(mesh):
     with tsmm.record_dispatches() as log:
         q = jax.jit(lambda x_, y_: tsmm.tsmm_t(x_, y_))(x, y)
 execs = [(e.entry, e.kind, e.executor) for e in log]
@@ -77,7 +77,7 @@ np.testing.assert_allclose(np.asarray(q), np.asarray(x.T @ y),
 
 # --- fallbacks: non-divisible tall dim / shard_map="never" --------------
 a_odd = a[:8191]
-with mesh:
+with jax.set_mesh(mesh):
     with tsmm.record_dispatches() as log:
         jax.jit(lambda a_, b_: tsmm.tsmm(a_, b_))(a_odd, b)
     assert [e.executor for e in log] == ["dense-xla"], log

@@ -111,15 +111,18 @@ def tsmt_split_spy(monkeypatch):
     orig_split = ops.tsmt_pallas_split
     orig_seq = ops.tsmt_pallas
 
-    def spy_split(x, y, *, block_m, block_a, splits, interpret=None):
+    def spy_split(x, y, *, block_m, block_a, splits, interpret,
+                  vmem_limit_bytes):
         calls["split"].append(splits)
         return orig_split(x, y, block_m=block_m, block_a=block_a,
-                          splits=splits, interpret=interpret)
+                          splits=splits, interpret=interpret,
+                          vmem_limit_bytes=vmem_limit_bytes)
 
-    def spy_seq(x, y, *, block_m, block_a, interpret=None):
+    def spy_seq(x, y, *, block_m, block_a, interpret, vmem_limit_bytes):
         calls["seq"] += 1
         return orig_seq(x, y, block_m=block_m, block_a=block_a,
-                        interpret=interpret)
+                        interpret=interpret,
+                        vmem_limit_bytes=vmem_limit_bytes)
 
     monkeypatch.setattr(ops, "tsmt_pallas_split", spy_split)
     monkeypatch.setattr(ops, "tsmt_pallas", spy_seq)
@@ -339,5 +342,5 @@ def test_reduce_partials_both_paths_match():
 def test_sum_partials_pallas_direct():
     p = jax.random.normal(jax.random.PRNGKey(1), (8, 256, 16), jnp.float32)
     got = sum_partials_pallas(p, block_r=64, out_dtype=jnp.float32,
-                              interpret=True)
+                              interpret=True, vmem_limit_bytes=1 << 22)
     np.testing.assert_allclose(got, jnp.sum(p, axis=0), rtol=1e-5, atol=1e-5)
