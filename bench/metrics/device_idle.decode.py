@@ -1,0 +1,7 @@
+"""Idle share of the device in the traced decode window (device trace)."""
+
+from bench import readings
+
+
+def read(run):
+    return readings.idle_pct(run)
