@@ -85,6 +85,7 @@ import math
 import os
 import warnings
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec
@@ -623,15 +624,21 @@ def _dispatch(entry: str, kind: str, executor: str, shape, policy, run,
     launches the run noted. Without listeners this is just ``run()`` --
     note_launch collectors only exist while a spy is attached. ``abft``
     is the guard mode stamped on the event: the caller passes the policy's
-    mode only for the dispatch the online wrap actually protects."""
+    mode only for the dispatch the online wrap actually protects.
+
+    ``run`` is traced under the profile scope ``tsmm.<kind>`` (``tsm2r``,
+    ``tsm2l``, ``tsmt`` or ``dense``), so a kernel launch or XLA dot in a
+    profile names the route the dispatcher chose for it."""
     if not _LISTENERS:
-        return run()
+        with jax.named_scope(f"tsmm.{kind}"):
+            return run()
     notes: list = []
     fault_notes: list = []
     _LAUNCH_NOTES.append(notes)
     _FAULT_NOTES.append(fault_notes)
     try:
-        out = run()
+        with jax.named_scope(f"tsmm.{kind}"):
+            out = run()
     finally:
         _FAULT_NOTES.pop()
         _LAUNCH_NOTES.pop()

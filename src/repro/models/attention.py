@@ -25,6 +25,7 @@ _NEG = -1e30
 # Core chunked attention
 # ---------------------------------------------------------------------------
 
+@layers.scoped("attn_core")
 def chunked_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                       q_offset=0, kv_valid_len=None,
                       q_chunk: int = 1024, kv_chunk: int = 1024,
@@ -34,6 +35,7 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int | None = None
     ``q_offset``: global position of q[0] (prefill continuation / decode).
     ``kv_valid_len``: mask out cache slots >= this (scalar or (B,)).
     Supports Dk != Dv (MLA attends with 192-dim keys, 128-dim values).
+    Profile scope ``attn_core``: scores, softmax and values.
     """
     b, sq, h, dk = q.shape
     _, skv, hk, _ = k.shape
@@ -110,6 +112,7 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int | None = None
     return out.astype(q.dtype)
 
 
+@layers.scoped("attn_core")
 def decode_attention(q, k_cache, v_cache, cur_len, *, window: int | None = None,
                      softmax_scale: float | None = None):
     """Single-step decode: q (B, 1, H, D) against a (B, S, Hk, D) cache.
@@ -172,6 +175,7 @@ def gqa_project_qkv(params, x, positions, *, n_heads, n_kv, head_dim,
     return q, k, v
 
 
+@layers.scoped("attn")
 def gqa_fwd(params, x, *, n_heads, n_kv, head_dim, causal=True,
             window=None, rope_theta=10000.0, rope_fraction=1.0,
             q_chunk=1024, kv_chunk=1024, positions=None,
@@ -179,7 +183,8 @@ def gqa_fwd(params, x, *, n_heads, n_kv, head_dim, causal=True,
     """Full-sequence attention (train / prefill). Returns (out, (k, v)).
 
     ``kv_override``: (k, v) to attend over instead of self-projections
-    (cross-attention passes pre-projected image keys/values).
+    (cross-attention passes pre-projected image keys/values). Profile
+    scope ``attn``: the projections and ``attn_core``.
     """
     b, s, _ = x.shape
     if positions is None:
@@ -195,6 +200,7 @@ def gqa_fwd(params, x, *, n_heads, n_kv, head_dim, causal=True,
     return out, (k, v)
 
 
+@layers.scoped("attn")
 def gqa_decode(params, x, cache_k, cache_v, pos, *, n_heads, n_kv, head_dim,
                window=None, rope_theta=10000.0, rope_fraction=1.0,
                ring_window: int | None = None):

@@ -45,6 +45,7 @@ def _mlp_init(key, cfg):
     return layers.swiglu_init(key, cfg.d_model, cfg.d_ff, _dtype(cfg))
 
 
+@layers.scoped("mlp")
 def _mlp_fwd(cfg, p, x):
     return (layers.gelu_mlp(p, x) if cfg.mlp_type == "gelu"
             else layers.swiglu(p, x))
@@ -137,8 +138,10 @@ def cross_kv(p, cfg, image_embeds):
 # Train forward (full sequence, no cache)
 # ---------------------------------------------------------------------------
 
+@layers.scoped("block")
 def block_fwd(p, x, cfg, kind: str, extras=None):
-    """Returns (x, metrics)."""
+    """Returns (x, metrics). Profile scope ``block``: one layer, with its
+    norms and residual adds."""
     metrics = {}
     if kind in ("attn_mlp", "attn_moe"):
         h, _ = attention.gqa_fwd(p["attn"], norm_apply(cfg, p["norm1"], x),
@@ -219,8 +222,10 @@ def cache_init(cfg, kind: str, batch: int, max_len: int):
     raise ValueError(kind)
 
 
+@layers.scoped("block")
 def block_prefill(p, x, cfg, kind: str, cache, extras=None):
-    """Full-sequence forward that also fills the cache. Returns (x, cache)."""
+    """Full-sequence forward that also fills the cache. Returns (x, cache).
+    Profile scope ``block``, as ``block_fwd``."""
     s = x.shape[1]
     if kind in ("attn_mlp", "attn_moe"):
         h, (k, v) = attention.gqa_fwd(
@@ -285,8 +290,10 @@ def block_prefill(p, x, cfg, kind: str, cache, extras=None):
     raise ValueError(kind)
 
 
+@layers.scoped("block")
 def block_decode(p, x, cfg, kind: str, cache, pos, extras=None):
-    """One-token step. x: (B,1,d). Returns (x, cache)."""
+    """One-token step. x: (B,1,d). Returns (x, cache). Profile scope
+    ``block``, as ``block_fwd``."""
     if kind in ("attn_mlp", "attn_moe"):
         ring = (cfg.attn_window
                 if cfg.attn_window and cache["k"].shape[1] == cfg.attn_window

@@ -18,6 +18,25 @@ import functools
 from repro.core import tsmm
 
 
+def scoped(name: str):
+    """Trace the decorated function under ``jax.named_scope(name)``.
+
+    The scope is a component of the ``op_name`` of every HLO instruction
+    the function emits, so a profile names the model's layers: an op of a
+    projection inside an RWKV time-mix of a layer reads
+    ``.../layers/while/body/.../block/time_mix/dense/tsmm.dense/dot_general``.
+    Scopes are trace-time metadata and change no instruction. The scope is
+    looked up at call time, so a test can swap ``jax.named_scope``.
+    """
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped_fn(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped_fn
+    return wrap
+
+
 def dense_init(key, d_in: int, d_out: int, dtype, scale: float | None = None):
     scale = scale if scale is not None else d_in ** -0.5
     return (jax.random.normal(key, (d_in, d_out), jnp.float32) * scale).astype(dtype)
@@ -62,6 +81,7 @@ def _dense_pg_bwd(policy, res, dy):
 _dense_pg.defvjp(_dense_pg_fwd, _dense_pg_bwd)
 
 
+@scoped("dense")
 def dense(w, x):
     """x @ w over the trailing dim of x.
 
@@ -77,6 +97,9 @@ def dense(w, x):
     hatch (A/B arms still need separate jit caches). When the scope sets
     ``param_dtype_grads``, the custom-VJP ``_dense_pg`` variant owns the
     backward dtype.
+
+    Profile scope ``dense``: every projection's device time, the LoRA
+    halves included, with the routed GEMM under ``tsmm.<kind>``.
     """
     p = tsmm.current_policy()
     if x.ndim < 2:
@@ -212,7 +235,9 @@ def lora_init(key, d_in: int, d_out: int, rank: int, dtype):
     }
 
 
+@scoped("lora")
 def lora_apply(params, x, base_out=None):
+    """Profile scope ``lora``: the adapter pair, each half a ``dense``."""
     h = dense(params["a"], x)
     out = dense(params["b"], h)
     return out if base_out is None else base_out + out

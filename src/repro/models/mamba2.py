@@ -73,12 +73,14 @@ def _causal_conv(seq, w, b, prev=None):
     return jax.nn.silu((out + b).astype(jnp.float32)).astype(seq.dtype), new_prev
 
 
+@layers.scoped("mamba")
 def mamba2_fwd(params, x_in, cfg: Mamba2Config, *, initial_state=None,
                conv_state=None, return_state: bool = False):
     """x_in: (B, S, d_model). Chunked SSD scan.
 
     Returns out, or (out, (ssm_state, conv_state)) when return_state
-    (prefill needs the states to seed decode).
+    (prefill needs the states to seed decode). Profile scopes: ``mamba``,
+    with the chunked SSD scan under ``mamba/ssd``.
     """
     b, s, _ = x_in.shape
     di, h, n, g = cfg.d_inner, cfg.n_heads, cfg.state_dim, cfg.n_groups
@@ -96,54 +98,55 @@ def mamba2_fwd(params, x_in, cfg: Mamba2Config, *, initial_state=None,
     a_neg = -jnp.exp(params["A_log"])                                  # (H,)
     loga = dt * a_neg                                                  # log decay
 
-    lc = min(cfg.chunk, s)
-    while s % lc:
-        lc -= 1
-    nc = s // lc
-    xh = x.reshape(b, nc, lc, h, p).astype(jnp.float32)
-    bh = bb.reshape(b, nc, lc, g, n).astype(jnp.float32)
-    ch = cc.reshape(b, nc, lc, g, n).astype(jnp.float32)
-    dtc = dt.reshape(b, nc, lc, h)
-    logac = loga.reshape(b, nc, lc, h)
+    with jax.named_scope("ssd"):
+        lc = min(cfg.chunk, s)
+        while s % lc:
+            lc -= 1
+        nc = s // lc
+        xh = x.reshape(b, nc, lc, h, p).astype(jnp.float32)
+        bh = bb.reshape(b, nc, lc, g, n).astype(jnp.float32)
+        ch = cc.reshape(b, nc, lc, g, n).astype(jnp.float32)
+        dtc = dt.reshape(b, nc, lc, h)
+        logac = loga.reshape(b, nc, lc, h)
 
-    cum = jnp.cumsum(logac, axis=2)                                    # (B,nc,L,H)
+        cum = jnp.cumsum(logac, axis=2)                                    # (B,nc,L,H)
 
-    # Intra-chunk: scores[t, s'] = (C_t . B_s') * exp(cum_t - cum_s') * dt_s'
-    seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]                # (B,nc,L,L,H)
-    tri = jnp.tril(jnp.ones((lc, lc), bool))
-    decay = jnp.where(tri[None, None, :, :, None], jnp.exp(seg), 0.0)
-    cb = jnp.einsum("bclgn,bcsgn->bclsg", ch, bh)                      # (B,nc,L,L,G)
-    cb = jnp.repeat(cb, hg, axis=-1)                                   # -> (...,H)
-    scores = cb * decay * dtc[:, :, None, :, :]
-    y_intra = jnp.einsum("bclsh,bcshp->bclhp", scores, xh)
+        # Intra-chunk: scores[t, s'] = (C_t . B_s') * exp(cum_t - cum_s') * dt_s'
+        seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]                # (B,nc,L,L,H)
+        tri = jnp.tril(jnp.ones((lc, lc), bool))
+        decay = jnp.where(tri[None, None, :, :, None], jnp.exp(seg), 0.0)
+        cb = jnp.einsum("bclgn,bcsgn->bclsg", ch, bh)                      # (B,nc,L,L,G)
+        cb = jnp.repeat(cb, hg, axis=-1)                                   # -> (...,H)
+        scores = cb * decay * dtc[:, :, None, :, :]
+        y_intra = jnp.einsum("bclsh,bcshp->bclhp", scores, xh)
 
-    # Chunk-end states: S_c = sum_t exp(cum_L - cum_t) dt_t B_t x_t^T
-    dec_to_end = jnp.exp(cum[:, :, -1:, :] - cum)                      # (B,nc,L,H)
-    b_rep = jnp.repeat(bh, hg, axis=3)                                 # (B,nc,L,H,N)
-    s_chunk = jnp.einsum("bclhn,bclhp->bchnp",
-                         b_rep, xh * (dtc * dec_to_end)[..., None])
+        # Chunk-end states: S_c = sum_t exp(cum_L - cum_t) dt_t B_t x_t^T
+        dec_to_end = jnp.exp(cum[:, :, -1:, :] - cum)                      # (B,nc,L,H)
+        b_rep = jnp.repeat(bh, hg, axis=3)                                 # (B,nc,L,H,N)
+        s_chunk = jnp.einsum("bclhn,bclhp->bchnp",
+                             b_rep, xh * (dtc * dec_to_end)[..., None])
 
-    # Inter-chunk scan: carry state, emit state at chunk *start*.
-    chunk_decay = jnp.exp(cum[:, :, -1, :])                            # (B,nc,H)
+        # Inter-chunk scan: carry state, emit state at chunk *start*.
+        chunk_decay = jnp.exp(cum[:, :, -1, :])                            # (B,nc,H)
 
-    def scan_fn(state, inp):
-        s_c, dec = inp                                                 # (B,H,N,P), (B,H)
-        out_state = state
-        new_state = state * dec[..., None, None] + s_c
-        return new_state, out_state
+        def scan_fn(state, inp):
+            s_c, dec = inp                                                 # (B,H,N,P), (B,H)
+            out_state = state
+            new_state = state * dec[..., None, None] + s_c
+            return new_state, out_state
 
-    init = (jnp.zeros((b, h, n, p), jnp.float32) if initial_state is None
-            else initial_state.astype(jnp.float32))
-    final_state, s_starts = lax.scan(
-        scan_fn, init,
-        (jnp.moveaxis(s_chunk, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
-    s_starts = jnp.moveaxis(s_starts, 0, 1)                            # (B,nc,H,N,P)
+        init = (jnp.zeros((b, h, n, p), jnp.float32) if initial_state is None
+                else initial_state.astype(jnp.float32))
+        final_state, s_starts = lax.scan(
+            scan_fn, init,
+            (jnp.moveaxis(s_chunk, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
+        s_starts = jnp.moveaxis(s_starts, 0, 1)                            # (B,nc,H,N,P)
 
-    c_rep = jnp.repeat(ch, hg, axis=3)                                 # (B,nc,L,H,N)
-    y_inter = jnp.einsum("bclhn,bchnp->bclhp",
-                         c_rep * jnp.exp(cum)[..., None], s_starts)
+        c_rep = jnp.repeat(ch, hg, axis=3)                                 # (B,nc,L,H,N)
+        y_inter = jnp.einsum("bclhn,bchnp->bclhp",
+                             c_rep * jnp.exp(cum)[..., None], s_starts)
 
-    y = (y_intra + y_inter).reshape(b, s, di)
+        y = (y_intra + y_inter).reshape(b, s, di)
     y = y + (x.astype(jnp.float32).reshape(b, s, h, p)
              * params["D"][None, None, :, None]).reshape(b, s, di)
     y = y.astype(x_in.dtype)
@@ -154,8 +157,11 @@ def mamba2_fwd(params, x_in, cfg: Mamba2Config, *, initial_state=None,
     return out
 
 
+@layers.scoped("mamba")
 def mamba2_decode(params, x_in, state, conv_state, cfg: Mamba2Config):
-    """One token. x_in: (B, 1, d_model); state: (B, H, N, P) f32."""
+    """One token. x_in: (B, 1, d_model); state: (B, H, N, P) f32.
+
+    Profile scopes: ``mamba``, the state update under ``mamba/ssd``."""
     b = x_in.shape[0]
     di, h, n, g = cfg.d_inner, cfg.n_heads, cfg.state_dim, cfg.n_groups
     p = di // h
@@ -170,13 +176,14 @@ def mamba2_decode(params, x_in, state, conv_state, cfg: Mamba2Config):
 
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])[:, 0]  # (B,H)
     a = jnp.exp(dt * -jnp.exp(params["A_log"]))                             # (B,H)
-    xh = x.reshape(b, h, p).astype(jnp.float32)
-    b_rep = jnp.repeat(bb.reshape(b, g, n), hg, axis=1)                     # (B,H,N)
-    c_rep = jnp.repeat(cc.reshape(b, g, n), hg, axis=1)
+    with jax.named_scope("ssd"):
+        xh = x.reshape(b, h, p).astype(jnp.float32)
+        b_rep = jnp.repeat(bb.reshape(b, g, n), hg, axis=1)                     # (B,H,N)
+        c_rep = jnp.repeat(cc.reshape(b, g, n), hg, axis=1)
 
-    state = state * a[..., None, None] + jnp.einsum(
-        "bhn,bhp->bhnp", b_rep, xh * dt[..., None])
-    y = jnp.einsum("bhn,bhnp->bhp", c_rep, state)
+        state = state * a[..., None, None] + jnp.einsum(
+            "bhn,bhp->bhnp", b_rep, xh * dt[..., None])
+        y = jnp.einsum("bhn,bhnp->bhp", c_rep, state)
     y = y + xh * params["D"][None, :, None]
     y = y.reshape(b, 1, di).astype(x_in.dtype)
     y = layers.rmsnorm(params["norm"], y) * jax.nn.silu(z.astype(jnp.float32)).astype(x_in.dtype)

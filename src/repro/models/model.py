@@ -16,6 +16,17 @@ scan:
                  block with a per-group LoRA (scan carries only the LoRA).
 * llama3.2-vision: [vlm_group x 8] -- each group = 4 self layers (inner
                  scan) + 1 gated cross-attention layer.
+
+Profile scopes (``jax.named_scope``; components of every HLO ``op_name``,
+so a device profile names the model's layers): ``embed``; ``layers`` around
+each segment's scan, where an op outside any ``block`` or ``shared_block``
+is the scan's own work -- slicing the stacked weights and caches per layer
+and stacking the per-layer outputs; ``block`` per layer and
+``shared_block`` per application of zamba2's shared block, each with their
+sub-blocks' scopes beneath (``time_mix``/``wkv``, ``channel_mix``,
+``mamba``/``ssd``, ``attn``/``attn_core``, ``mlp``, ``lora``, and ``dense``
+with ``tsmm.<kind>`` for every projection); ``unembed``, the final norm and
+vocab projection.
 """
 
 from __future__ import annotations
@@ -126,19 +137,23 @@ def init(key, cfg):
 # Shared helpers
 # ---------------------------------------------------------------------------
 
+@layers.scoped("embed")
 def _embed_input(params, cfg, batch):
     if cfg.input_mode == "frames":
         return layers.dense(params["frame_proj"]["w"], batch["frames"])
     return layers.embed(params["embed"], batch["tokens"])
 
 
+@layers.scoped("unembed")
 def _logits(params, cfg, x):
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
     return layers.unembed(head, blocks.norm_apply(cfg, params["final_norm"], x))
 
 
+@layers.scoped("shared_block")
 def _shared_block_fwd(shared_p, lora_a, lora_f, x, cfg, mode, cache=None, pos=None):
-    """Zamba2's weight-shared attention block + per-application LoRA."""
+    """Zamba2's weight-shared attention block + per-application LoRA
+    (profile scope ``shared_block``)."""
     n1 = blocks.norm_apply(cfg, shared_p["norm1"], x)
     kw = blocks._attn_kwargs(cfg)
     if mode == "train":
@@ -156,7 +171,9 @@ def _shared_block_fwd(shared_p, lora_a, lora_f, x, cfg, mode, cache=None, pos=No
     h = h + layers.lora_apply(lora_a, n1)
     x = x + h
     n2 = blocks.norm_apply(cfg, shared_p["norm2"], x)
-    h2 = layers.swiglu(shared_p["ffn"], n2) + layers.lora_apply(lora_f, n2)
+    with jax.named_scope("mlp"):
+        h2 = layers.swiglu(shared_p["ffn"], n2)
+    h2 = h2 + layers.lora_apply(lora_f, n2)
     return x + h2, cache
 
 
@@ -248,7 +265,8 @@ def forward_hidden(params, cfg, batch):
                                          h, cfg, "train")
                 return h, None
 
-            x, _ = lax.scan(group_body, x, seg_p)
+            with jax.named_scope("layers"):
+                x, _ = lax.scan(group_body, x, seg_p)
         elif seg.kind == "vlm_group":
             def vgroup_body(h, xs):
                 def self_body(hh, lp):
@@ -259,9 +277,11 @@ def forward_hidden(params, cfg, batch):
                 h, _ = blocks.block_fwd(xs["cross"], h, cfg, "cross_mlp", extras)
                 return h, None
 
-            x, _ = lax.scan(_maybe_remat(cfg, vgroup_body), x, seg_p)
+            with jax.named_scope("layers"):
+                x, _ = lax.scan(_maybe_remat(cfg, vgroup_body), x, seg_p)
         else:
-            x, mets = _scan_layers_remat(cfg, seg_p, x, seg.kind, seg.n)
+            with jax.named_scope("layers"):
+                x, mets = _scan_layers_remat(cfg, seg_p, x, seg.kind, seg.n)
             if mets:
                 all_metrics.append(jax.tree.map(jnp.sum, mets))
 
@@ -326,7 +346,8 @@ def prefill(params, cfg, batch, cache):
                     cache=gc["shared"])
                 return h, {"mamba": mamba_c, "shared": shared_c}
 
-            x, nc = lax.scan(group_body, x, (seg_p, seg_c))
+            with jax.named_scope("layers"):
+                x, nc = lax.scan(group_body, x, (seg_p, seg_c))
         elif seg.kind == "vlm_group":
             def vgroup_body(h, xs):
                 gp, gc = xs
@@ -341,14 +362,16 @@ def prefill(params, cfg, batch, cache):
                                                   "cross_mlp", gc["cross"], extras)
                 return h, {"self": self_c, "cross": cross_c}
 
-            x, nc = lax.scan(vgroup_body, x, (seg_p, seg_c))
+            with jax.named_scope("layers"):
+                x, nc = lax.scan(vgroup_body, x, (seg_p, seg_c))
         else:
             def body(h, xs, kind=seg.kind):
                 lp, lc = xs
                 out, nc2 = blocks.block_prefill(lp, h, cfg, kind, lc)
                 return out, nc2
 
-            x, nc = lax.scan(body, x, (seg_p, seg_c))
+            with jax.named_scope("layers"):
+                x, nc = lax.scan(body, x, (seg_p, seg_c))
         new_caches.append(nc)
 
     logits = _logits(params, cfg, x[:, -1:])[:, 0]
@@ -357,7 +380,8 @@ def prefill(params, cfg, batch, cache):
 
 def decode_step(params, cfg, tokens, pos, cache):
     """tokens: (B, 1) int32; pos: scalar int32. Returns (logits (B,V), cache)."""
-    x = layers.embed(params["embed"], tokens)
+    with jax.named_scope("embed"):
+        x = layers.embed(params["embed"], tokens)
     new_caches = []
 
     for seg, seg_p, seg_c in zip(segments(cfg), params["segments"], cache):
@@ -378,7 +402,8 @@ def decode_step(params, cfg, tokens, pos, cache):
                     cache=gc["shared"], pos=pos)
                 return h, {"mamba": mamba_c, "shared": shared_c}
 
-            x, nc = lax.scan(group_body, x, (seg_p, seg_c))
+            with jax.named_scope("layers"):
+                x, nc = lax.scan(group_body, x, (seg_p, seg_c))
         elif seg.kind == "vlm_group":
             def vgroup_body(h, xs):
                 gp, gc = xs
@@ -393,14 +418,16 @@ def decode_step(params, cfg, tokens, pos, cache):
                                                  "cross_mlp", gc["cross"], pos)
                 return h, {"self": self_c, "cross": cross_c}
 
-            x, nc = lax.scan(vgroup_body, x, (seg_p, seg_c))
+            with jax.named_scope("layers"):
+                x, nc = lax.scan(vgroup_body, x, (seg_p, seg_c))
         else:
             def body(h, xs, kind=seg.kind):
                 lp, lc = xs
                 out, nc2 = blocks.block_decode(lp, h, cfg, kind, lc, pos)
                 return out, nc2
 
-            x, nc = lax.scan(body, x, (seg_p, seg_c))
+            with jax.named_scope("layers"):
+                x, nc = lax.scan(body, x, (seg_p, seg_c))
         new_caches.append(nc)
 
     logits = _logits(params, cfg, x)[:, 0]

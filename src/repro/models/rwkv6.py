@@ -90,63 +90,68 @@ def _out_stage(params, y, g, h, dh):
     return layers.dense(params["wo"], y * jax.nn.silu(g.astype(jnp.float32)).astype(g.dtype))
 
 
+@layers.scoped("time_mix")
 def rwkv6_time_mix(params, x, cfg: RWKV6Config, *, state=None, x_prev=None,
                    return_state: bool = False):
-    """Chunked evaluation. x: (B,S,d). state: (B,H,D,D) f32."""
+    """Chunked evaluation. x: (B,S,d). state: (B,H,D,D) f32.
+
+    Profile scopes: ``time_mix``, with the WKV sequence mixer (chunk
+    scores, inter-chunk state scan) under ``time_mix/wkv``."""
     b, s, d = x.shape
     h, dh = cfg.n_heads, cfg.head_dim
     if x_prev is None:
         x_prev = jnp.zeros((b, 1, d), x.dtype)
     r, k, v, g, logw = _projections(params, x, x_prev)
-    rh = _headed(r, h, dh).astype(jnp.float32)
-    kh = _headed(k, h, dh).astype(jnp.float32)
-    vh = _headed(v, h, dh).astype(jnp.float32)
-    lw = _headed(logw, h, dh)                              # (B,S,H,D)
+    with jax.named_scope("wkv"):
+        rh = _headed(r, h, dh).astype(jnp.float32)
+        kh = _headed(k, h, dh).astype(jnp.float32)
+        vh = _headed(v, h, dh).astype(jnp.float32)
+        lw = _headed(logw, h, dh)                              # (B,S,H,D)
 
-    lc = min(cfg.chunk, s)
-    while s % lc:
-        lc -= 1
-    nc = s // lc
-    rc = rh.reshape(b, nc, lc, h, dh)
-    kc = kh.reshape(b, nc, lc, h, dh)
-    vc = vh.reshape(b, nc, lc, h, dh)
-    lwc = lw.reshape(b, nc, lc, h, dh)
-    cum = jnp.cumsum(lwc, axis=2)                          # inclusive
+        lc = min(cfg.chunk, s)
+        while s % lc:
+            lc -= 1
+        nc = s // lc
+        rc = rh.reshape(b, nc, lc, h, dh)
+        kc = kh.reshape(b, nc, lc, h, dh)
+        vc = vh.reshape(b, nc, lc, h, dh)
+        lwc = lw.reshape(b, nc, lc, h, dh)
+        cum = jnp.cumsum(lwc, axis=2)                          # inclusive
 
-    # Intra-chunk: for s' < t: A[t,s'] = sum_d r_t[d] k_s'[d] exp(cum_{t-1} - cum_{s'})[d]
-    # (decay applies on steps s'+1 .. t-1; y_t reads S_{t-1}).
-    cum_tm1 = cum - lwc                                    # cum_{t-1}
-    # scores via exp-trick: exp(cum_tm1_t - cum_s') = exp(cum_tm1_t) * exp(-cum_s')
-    # is numerically unsafe; use pairwise difference instead (L is small).
-    diff = cum_tm1[:, :, :, None, :, :] - cum[:, :, None, :, :, :]   # (B,nc,L,L,H,D)
-    strict = jnp.tril(jnp.ones((lc, lc), bool), k=-1)
-    dec = jnp.where(strict[None, None, :, :, None, None], jnp.exp(diff), 0.0)
-    scores = jnp.einsum("bcthd,bcshd,bctshd->bctsh", rc, kc, dec)
-    y_intra = jnp.einsum("bctsh,bcshd->bcthd", scores, vc)
-    # Diagonal (current token) via bonus u:
-    y_diag = (rc * kc * params["u"][None, None, None]).sum(-1, keepdims=True) * vc
-    y_intra = y_intra + y_diag
+        # Intra-chunk: for s' < t: A[t,s'] = sum_d r_t[d] k_s'[d] exp(cum_{t-1} - cum_{s'})[d]
+        # (decay applies on steps s'+1 .. t-1; y_t reads S_{t-1}).
+        cum_tm1 = cum - lwc                                    # cum_{t-1}
+        # scores via exp-trick: exp(cum_tm1_t - cum_s') = exp(cum_tm1_t) * exp(-cum_s')
+        # is numerically unsafe; use pairwise difference instead (L is small).
+        diff = cum_tm1[:, :, :, None, :, :] - cum[:, :, None, :, :, :]   # (B,nc,L,L,H,D)
+        strict = jnp.tril(jnp.ones((lc, lc), bool), k=-1)
+        dec = jnp.where(strict[None, None, :, :, None, None], jnp.exp(diff), 0.0)
+        scores = jnp.einsum("bcthd,bcshd,bctshd->bctsh", rc, kc, dec)
+        y_intra = jnp.einsum("bctsh,bcshd->bcthd", scores, vc)
+        # Diagonal (current token) via bonus u:
+        y_diag = (rc * kc * params["u"][None, None, None]).sum(-1, keepdims=True) * vc
+        y_intra = y_intra + y_diag
 
-    # Chunk-end state contributions: sum_t exp(cum_L - cum_t) k_t v_t^T
-    dec_end = jnp.exp(cum[:, :, -1:, :, :] - cum)          # (B,nc,L,H,D)
-    s_chunk = jnp.einsum("bcthd,bcthe->bchde", kc * dec_end, vc)
-    chunk_decay = jnp.exp(cum[:, :, -1])                   # (B,nc,H,D)
+        # Chunk-end state contributions: sum_t exp(cum_L - cum_t) k_t v_t^T
+        dec_end = jnp.exp(cum[:, :, -1:, :, :] - cum)          # (B,nc,L,H,D)
+        s_chunk = jnp.einsum("bcthd,bcthe->bchde", kc * dec_end, vc)
+        chunk_decay = jnp.exp(cum[:, :, -1])                   # (B,nc,H,D)
 
-    def scan_fn(st, inp):
-        sc, dec_c = inp
-        out_st = st
-        return st * dec_c[..., None] + sc, out_st
+        def scan_fn(st, inp):
+            sc, dec_c = inp
+            out_st = st
+            return st * dec_c[..., None] + sc, out_st
 
-    init = jnp.zeros((b, h, dh, dh), jnp.float32) if state is None else state
-    final_state, s_starts = lax.scan(
-        scan_fn, init, (jnp.moveaxis(s_chunk, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
-    s_starts = jnp.moveaxis(s_starts, 0, 1)                # (B,nc,H,D,D)
+        init = jnp.zeros((b, h, dh, dh), jnp.float32) if state is None else state
+        final_state, s_starts = lax.scan(
+            scan_fn, init, (jnp.moveaxis(s_chunk, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
+        s_starts = jnp.moveaxis(s_starts, 0, 1)                # (B,nc,H,D,D)
 
-    # Inter-chunk: y_t += r_t (exp(cum_{t-1}) .) S_in
-    r_dec = rc * jnp.exp(cum_tm1)
-    y_inter = jnp.einsum("bcthd,bchde->bcthe", r_dec, s_starts)
+        # Inter-chunk: y_t += r_t (exp(cum_{t-1}) .) S_in
+        r_dec = rc * jnp.exp(cum_tm1)
+        y_inter = jnp.einsum("bcthd,bchde->bcthe", r_dec, s_starts)
 
-    y = (y_intra + y_inter).reshape(b, s, h, dh)
+        y = (y_intra + y_inter).reshape(b, s, h, dh)
     out = _out_stage(params, y, g, h, dh)
     if return_state:
         return out, (final_state, x[:, -1:])
@@ -179,19 +184,23 @@ def rwkv6_time_mix_ref(params, x, cfg: RWKV6Config):
     return _out_stage(params, y, g, h, dh)
 
 
+@layers.scoped("time_mix")
 def rwkv6_time_mix_decode(params, x, state, x_prev, cfg: RWKV6Config):
-    """One token. x: (B,1,d); state: (B,H,D,D); x_prev: (B,1,d)."""
+    """One token. x: (B,1,d); state: (B,H,D,D); x_prev: (B,1,d).
+
+    Profile scopes: ``time_mix``, the state update under ``time_mix/wkv``."""
     h, dh = cfg.n_heads, cfg.head_dim
     r, k, v, g, logw = _projections(params, x, x_prev)
-    rt = _headed(r, h, dh)[:, 0].astype(jnp.float32)
-    kt = _headed(k, h, dh)[:, 0].astype(jnp.float32)
-    vt = _headed(v, h, dh)[:, 0].astype(jnp.float32)
-    wt = jnp.exp(_headed(logw, h, dh)[:, 0])
-    kv = jnp.einsum("bhd,bhe->bhde", kt, vt)
-    wkv = state + params["u"][None, :, :, None] * kv
-    # repro: allow-raw-param-matmul (wkv is recurrent state; see time_mix)
-    yt = jnp.einsum("bhd,bhde->bhe", rt, wkv)[:, None]      # (B,1,H,D)
-    new_state = state * wt[..., None] + kv
+    with jax.named_scope("wkv"):
+        rt = _headed(r, h, dh)[:, 0].astype(jnp.float32)
+        kt = _headed(k, h, dh)[:, 0].astype(jnp.float32)
+        vt = _headed(v, h, dh)[:, 0].astype(jnp.float32)
+        wt = jnp.exp(_headed(logw, h, dh)[:, 0])
+        kv = jnp.einsum("bhd,bhe->bhde", kt, vt)
+        wkv = state + params["u"][None, :, :, None] * kv
+        # repro: allow-raw-param-matmul (wkv is recurrent state; see time_mix)
+        yt = jnp.einsum("bhd,bhde->bhe", rt, wkv)[:, None]      # (B,1,H,D)
+        new_state = state * wt[..., None] + kv
     out = _out_stage(params, yt, g, h, dh)
     return out, new_state, x
 
@@ -210,7 +219,9 @@ def rwkv6_channel_mix_init(key, d_model: int, d_ff: int, dtype):
     }
 
 
+@layers.scoped("channel_mix")
 def rwkv6_channel_mix(params, x, *, x_prev=None, return_state: bool = False):
+    """RWKV's FFN; profile scope ``channel_mix``."""
     b, s, d = x.shape
     if x_prev is None:
         x_prev = jnp.zeros((b, 1, d), x.dtype)

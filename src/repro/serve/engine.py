@@ -54,6 +54,10 @@ def make_serve_fns(cfg, policy: "tsmm.GemmPolicy | None" = None, *,
     the fully-fused path -- int8 tiles all the way into the Pallas GEMMs
     via ``GemmPolicy(quant="int8")`` -- re-quantizes activations on the
     fly and is the policy knob, not the storage format.)
+
+    In a device profile every op of a step carries the scope
+    ``serve.prefill`` or ``serve.decode`` in its ``op_name``, above the
+    model's own scopes (``models/model.py``).
     """
     def _scope():
         base = policy
@@ -64,12 +68,12 @@ def make_serve_fns(cfg, policy: "tsmm.GemmPolicy | None" = None, *,
                 else contextlib.nullcontext())
 
     def prefill_step(params, batch, cache):
-        with _scope():
+        with _scope(), jax.named_scope("serve.prefill"):
             params = kquant.dequantize_weights(params, _WEIGHT_DTYPE)
             return model.prefill(params, cfg, batch, cache)
 
     def decode_step(params, tokens, pos, cache):
-        with _scope():
+        with _scope(), jax.named_scope("serve.decode"):
             params = kquant.dequantize_weights(params, _WEIGHT_DTYPE)
             return model.decode_step(params, cfg, tokens, pos, cache)
 
