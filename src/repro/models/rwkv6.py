@@ -9,9 +9,15 @@ distinguishes RWKV6; the decay LoRA (rank 64) is a tall-and-skinny GEMM
 pair served by the TSM2X dispatcher at large batch*seq.
 
 Two evaluation paths:
-* ``rwkv6_time_mix`` -- chunked matmul form (training/prefill): intra-chunk
-  (L x L) decay-weighted scores + inter-chunk state scan, mirroring the
-  chunked-GLA decomposition. This is the MXU-friendly formulation.
+* ``rwkv6_time_mix`` -- chunked form (training/prefill), mirroring the
+  chunked-GLA decomposition. The intra-chunk (L x L) scores are one
+  elementwise expression over (L, L, D) reduced over D, which XLA fuses
+  into one reduction on the vector units (the per-channel decay keeps
+  them off the MXU); nothing of size L x L x D is written to memory. The
+  readouts, the chunk-end state contributions and the inter-chunk readout
+  are batched matmuls on the MXU, the current token's bonus ``u`` an
+  elementwise f32 product, and the inter-chunk state is carried by a
+  sequential scan over chunks.
 * ``rwkv6_time_mix_ref`` -- per-step lax.scan oracle (tests + a perf
   baseline for §Perf: the step form has O(1) arithmetic intensity, the
   chunked form lifts it by ~L).
@@ -90,13 +96,31 @@ def _out_stage(params, y, g, h, dh):
     return layers.dense(params["wo"], y * jax.nn.silu(g.astype(jnp.float32)).astype(g.dtype))
 
 
+def _intra_scores(rc, kc, cum, cum_tm1):
+    """Intra-chunk scores A[t, s] = sum_d r_t[d] k_s[d] exp(cum_{t-1} - cum_s)[d]
+    for s < t, 0 for s >= t: (B, nc, L, L, H) from (B, nc, L, H, D) operands.
+
+    The decay stays pairwise: factored as exp(cum_{t-1}) exp(-cum_s) it
+    overflows once a chunk decays past e^-88. One elementwise expression
+    reduced over d, so XLA emits one reduce fusion whose only output is
+    the scores. The mask is applied to the exponent: masked pairs read
+    exp(-inf) = 0, so no positive exponent is evaluated, whatever the
+    decay (and no inf reaches a gradient)."""
+    pos = jnp.arange(rc.shape[2])
+    strict = (pos[:, None] > pos[None, :])[:, :, None, None]
+    dec = jnp.exp(jnp.where(strict, cum_tm1[:, :, :, None] - cum[:, :, None], -jnp.inf))
+    return (rc[:, :, :, None] * kc[:, :, None] * dec).sum(-1)
+
+
 @layers.scoped("time_mix")
 def rwkv6_time_mix(params, x, cfg: RWKV6Config, *, state=None, x_prev=None,
                    return_state: bool = False):
     """Chunked evaluation. x: (B,S,d). state: (B,H,D,D) f32.
 
-    Profile scopes: ``time_mix``, with the WKV sequence mixer (chunk
-    scores, inter-chunk state scan) under ``time_mix/wkv``."""
+    Profile scopes: ``time_mix``, with the WKV sequence mixer under
+    ``time_mix/wkv``: the intra-chunk scores and their readout under
+    ``wkv/intra``, the inter-chunk state scan and its readout under
+    ``wkv/state``."""
     b, s, d = x.shape
     h, dh = cfg.n_heads, cfg.head_dim
     if x_prev is None:
@@ -117,41 +141,40 @@ def rwkv6_time_mix(params, x, cfg: RWKV6Config, *, state=None, x_prev=None,
         vc = vh.reshape(b, nc, lc, h, dh)
         lwc = lw.reshape(b, nc, lc, h, dh)
         cum = jnp.cumsum(lwc, axis=2)                          # inclusive
-
-        # Intra-chunk: for s' < t: A[t,s'] = sum_d r_t[d] k_s'[d] exp(cum_{t-1} - cum_{s'})[d]
-        # (decay applies on steps s'+1 .. t-1; y_t reads S_{t-1}).
+        # Decay applies on steps s+1 .. t-1: y_t reads S_{t-1}.
         cum_tm1 = cum - lwc                                    # cum_{t-1}
-        # scores via exp-trick: exp(cum_tm1_t - cum_s') = exp(cum_tm1_t) * exp(-cum_s')
-        # is numerically unsafe; use pairwise difference instead (L is small).
-        diff = cum_tm1[:, :, :, None, :, :] - cum[:, :, None, :, :, :]   # (B,nc,L,L,H,D)
-        strict = jnp.tril(jnp.ones((lc, lc), bool), k=-1)
-        dec = jnp.where(strict[None, None, :, :, None, None], jnp.exp(diff), 0.0)
-        scores = jnp.einsum("bcthd,bcshd,bctshd->bctsh", rc, kc, dec)
-        y_intra = jnp.einsum("bctsh,bcshd->bcthd", scores, vc)
-        # Diagonal (current token) via bonus u:
-        y_diag = (rc * kc * params["u"][None, None, None]).sum(-1, keepdims=True) * vc
-        y_intra = y_intra + y_diag
 
-        # Chunk-end state contributions: sum_t exp(cum_L - cum_t) k_t v_t^T
-        dec_end = jnp.exp(cum[:, :, -1:, :, :] - cum)          # (B,nc,L,H,D)
-        s_chunk = jnp.einsum("bcthd,bcthe->bchde", kc * dec_end, vc)
-        chunk_decay = jnp.exp(cum[:, :, -1])                   # (B,nc,H,D)
+        with jax.named_scope("intra"):
+            scores = _intra_scores(rc, kc, cum, cum_tm1)       # (B,nc,L,L,H)
+            y_intra = jnp.einsum("bctsh,bcshd->bcthd", scores, vc)
+            # Diagonal (current token) via bonus u: an elementwise f32
+            # product, kept out of the readout (a default-precision matmul
+            # may round its operands to bf16), in the projections' (B,S,d)
+            # layout rather than the chunked one.
+            ruk = r.astype(jnp.float32) * k.astype(jnp.float32) * params["u"].reshape(-1)
+            y_diag = _headed(ruk, h, dh).sum(-1, keepdims=True) * vh
 
-        def scan_fn(st, inp):
-            sc, dec_c = inp
-            out_st = st
-            return st * dec_c[..., None] + sc, out_st
+        with jax.named_scope("state"):
+            # Chunk-end state contributions: sum_t exp(cum_L - cum_t) k_t v_t^T
+            dec_end = jnp.exp(cum[:, :, -1:, :, :] - cum)      # (B,nc,L,H,D)
+            s_chunk = jnp.einsum("bcthd,bcthe->bchde", kc * dec_end, vc)
+            chunk_decay = jnp.exp(cum[:, :, -1])               # (B,nc,H,D)
 
-        init = jnp.zeros((b, h, dh, dh), jnp.float32) if state is None else state
-        final_state, s_starts = lax.scan(
-            scan_fn, init, (jnp.moveaxis(s_chunk, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
-        s_starts = jnp.moveaxis(s_starts, 0, 1)                # (B,nc,H,D,D)
+            def scan_fn(st, inp):
+                sc, dec_c = inp
+                out_st = st
+                return st * dec_c[..., None] + sc, out_st
 
-        # Inter-chunk: y_t += r_t (exp(cum_{t-1}) .) S_in
-        r_dec = rc * jnp.exp(cum_tm1)
-        y_inter = jnp.einsum("bcthd,bchde->bcthe", r_dec, s_starts)
+            init = jnp.zeros((b, h, dh, dh), jnp.float32) if state is None else state
+            final_state, s_starts = lax.scan(
+                scan_fn, init, (jnp.moveaxis(s_chunk, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
+            s_starts = jnp.moveaxis(s_starts, 0, 1)            # (B,nc,H,D,D)
 
-        y = (y_intra + y_inter).reshape(b, s, h, dh)
+            # Inter-chunk: y_t += r_t (exp(cum_{t-1}) .) S_in
+            r_dec = rc * jnp.exp(cum_tm1)
+            y_inter = jnp.einsum("bcthd,bchde->bcthe", r_dec, s_starts)
+
+        y = (y_intra + y_inter).reshape(b, s, h, dh) + y_diag
     out = _out_stage(params, y, g, h, dh)
     if return_state:
         return out, (final_state, x[:, -1:])

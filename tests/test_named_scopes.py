@@ -15,7 +15,8 @@ from repro.serve import engine
 
 EXPECTED = {
     "rwkv6-1.6b": {"serve.prefill", "serve.decode", "embed", "layers", "block",
-                   "time_mix", "wkv", "channel_mix", "dense", "lora", "unembed"},
+                   "time_mix", "wkv", "intra", "state", "channel_mix", "dense",
+                   "lora", "unembed"},
     "zamba2-1.2b": {"serve.prefill", "serve.decode", "embed", "layers", "block",
                     "shared_block", "mamba", "ssd", "attn", "attn_core", "mlp",
                     "dense", "lora", "unembed"},

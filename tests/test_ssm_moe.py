@@ -1,5 +1,7 @@
 """Mamba2 chunked-vs-recurrent, RWKV6 chunked-vs-step, MoE invariants."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -73,12 +75,95 @@ def _rwkv_params(key, d=32):
     return rwkv6.rwkv6_time_mix_init(key, d, CFG_R, jnp.float32)
 
 
-def test_rwkv6_chunked_matches_step():
+def _strong_decay(p, key, w0=2.0):
+    """Decays of exp(-exp(w0 + lora)): around -7.4 per step, and past -8
+    where the LoRA (random up-projection) pushes them."""
+    a = p["w_lora"]["a"]
+    up = 0.5 * jax.random.normal(key, p["w_lora"]["b"].shape) / a.shape[1] ** 0.5
+    return dict(p, w0=jnp.full_like(p["w0"], w0), w_lora=dict(p["w_lora"], b=up))
+
+
+def _step_state(p, x, cfg):
+    """The state the per-step recurrence ends in: decode over every token."""
+    b, _, d = x.shape
+
+    def step(carry, xt):
+        st, prev = carry
+        _, st, prev = rwkv6.rwkv6_time_mix_decode(p, xt[:, None], st, prev, cfg)
+        return (st, prev), None
+
+    init = (jnp.zeros((b, cfg.n_heads, cfg.head_dim, cfg.head_dim)), jnp.zeros((b, 1, d)))
+    (st, _), _ = jax.lax.scan(step, init, jnp.moveaxis(x, 1, 0))
+    return st
+
+
+@pytest.mark.parametrize("chunk,seq,strong", [(8, 24, False), (32, 128, True),
+                                              (64, 128, True)],
+                         ids=["chunk8", "strong-chunk32", "strong-chunk64"])
+def test_rwkv6_chunked_matches_step(chunk, seq, strong):
+    """Chunked form == per-step oracle, in output and final state; with
+    strong decays (log-decay past -8 a step) nothing overflows."""
+    cfg = dataclasses.replace(CFG_R, chunk=chunk)
     p = _rwkv_params(jax.random.PRNGKey(0))
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, 32))
-    got = rwkv6.rwkv6_time_mix(p, x, CFG_R)
-    want = rwkv6.rwkv6_time_mix_ref(p, x, CFG_R)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, seq, 32))
+    if strong:
+        p = _strong_decay(p, jax.random.PRNGKey(8))
+        logw = rwkv6._projections(p, x, jnp.zeros((2, 1, 32)))[-1]
+        assert float(logw.min()) < -8.0
+    got, (st, _) = rwkv6.rwkv6_time_mix(p, x, cfg, return_state=True)
+    assert np.isfinite(np.asarray(got)).all()
+    want = rwkv6.rwkv6_time_mix_ref(p, x, cfg)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(st, _step_state(p, x, cfg), rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("chunk,seq", [(8, 24), (32, 64)])
+def test_rwkv6_chunked_grad_matches_step(chunk, seq):
+    """jax.grad through the chunked form (the training path) == through the
+    per-step oracle, with strong decays: no masked exponent leaks a NaN."""
+    cfg = dataclasses.replace(CFG_R, chunk=chunk)
+    p = _strong_decay(_rwkv_params(jax.random.PRNGKey(0)), jax.random.PRNGKey(9))
+    x = jax.random.normal(jax.random.PRNGKey(10), (2, seq, 32))
+    w = jax.random.normal(jax.random.PRNGKey(11), (2, seq, 32))
+
+    def loss(fn):
+        return lambda p, x: jnp.sum(w * fn(p, x, cfg))
+
+    got = jax.grad(loss(rwkv6.rwkv6_time_mix), argnums=(0, 1))(p, x)
+    want = jax.grad(loss(rwkv6.rwkv6_time_mix_ref), argnums=(0, 1))(p, x)
+    for (path, g), r in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want)):
+        assert np.isfinite(np.asarray(g)).all(), path
+        np.testing.assert_allclose(g, r, rtol=1e-3, atol=1e-3 * float(jnp.abs(r).max()),
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+def test_rwkv6_bonus_exact_under_bf16_matmuls(monkeypatch):
+    """The bonus u stays an f32 product where a default-precision matmul
+    rounds its f32 operands to bf16, as the TPU's does (the CPU computes
+    such a matmul in f32, so the rounding is applied here by hand): the
+    chunked form's y(u) - y(0) is r_t (u * k_t) v_t to f32 accuracy."""
+    einsum = jnp.einsum
+
+    def bf16_einsum(spec, *ops, precision=None, **kw):
+        if precision is None:
+            ops = [o.astype(jnp.bfloat16).astype(o.dtype) if o.dtype == jnp.float32 else o
+                   for o in ops]
+        return einsum(spec, *ops, precision=precision, **kw)
+
+    monkeypatch.setattr(jnp, "einsum", bf16_einsum)
+    monkeypatch.setattr(rwkv6, "_out_stage", lambda params, y, g, h, dh: y)
+    h, dh = CFG_R.n_heads, CFG_R.head_dim
+    p = _rwkv_params(jax.random.PRNGKey(0))
+    p = dict(p, u=jax.random.normal(jax.random.PRNGKey(12), (h, dh)))
+    x = jax.random.normal(jax.random.PRNGKey(13), (2, 24, 32))
+    y = rwkv6.rwkv6_time_mix(p, x, CFG_R)
+    y0 = rwkv6.rwkv6_time_mix(dict(p, u=jnp.zeros_like(p["u"])), x, CFG_R)
+    r, k, v = (a.reshape(2, 24, h, dh)
+               for a in rwkv6._projections(p, x, jnp.zeros((2, 1, 32)))[:3])
+    bonus = (r * k * p["u"]).sum(-1, keepdims=True) * v
+    np.testing.assert_allclose(y - y0, bonus, rtol=1e-5,
+                               atol=1e-5 * float(jnp.abs(bonus).max()))
 
 
 @pytest.mark.parametrize("chunk", [4, 6, 24])
