@@ -11,6 +11,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+from repro.models.layers import YaRN
 from repro.models.mamba2 import Mamba2Config
 from repro.models.moe import MoEConfig
 from repro.models.rwkv6 import RWKV6Config
@@ -23,6 +24,7 @@ class MLAConfig:
     nope_dim: int = 128
     rope_dim: int = 64
     v_dim: int = 128
+    yarn: Optional[YaRN] = None    # deepseek-v3: factor 40 over 4096 positions
 
 
 @dataclasses.dataclass(frozen=True)

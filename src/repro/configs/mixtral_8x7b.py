@@ -13,8 +13,7 @@ CONFIG = ModelConfig(
     n_layers=32, d_model=4096, n_heads=32, n_kv_heads=8, d_ff=14336,
     vocab_size=32000, head_dim=128,
     rope_theta=1000000.0, attn_window=4096,
-    moe=MoEConfig(n_experts=8, top_k=2, d_ff_expert=14336, router="softmax",
-                  capacity_factor=1.25),
+    moe=MoEConfig(n_experts=8, top_k=2, d_ff_expert=14336, router="softmax"),
     dtype="bfloat16", microbatch=4,
 )
 
@@ -24,7 +23,6 @@ def smoke() -> ModelConfig:
         name="mixtral-smoke", family="moe",
         n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
         vocab_size=256, head_dim=16, attn_window=16,
-        moe=MoEConfig(n_experts=4, top_k=2, d_ff_expert=64, router="softmax",
-                      capacity_factor=8.0),   # drop-free for smoke determinism
+        moe=MoEConfig(n_experts=4, top_k=2, d_ff_expert=64, router="softmax"),
         q_chunk=16, kv_chunk=16, dtype="float32",
     )

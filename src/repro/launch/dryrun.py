@@ -81,7 +81,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
     from repro.configs.base import SHAPES
     from repro.distributed import sharding
     from repro.launch.mesh import make_production_mesh
-    from repro.models import model
+    from repro.models import model, moe
     from repro.optim import adamw, schedule
     from repro.roofline import analyze
     from repro.train import train_step as ts
@@ -97,11 +97,11 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
     mesh = make_production_mesh(multi_pod=multi_pod)
     n_chips = mesh.size
     if cfg.moe is not None:
-        # group-local MoE dispatch: one group per DP shard
-        dp = n_chips // mesh.shape["model"]
-        groups = dp if (shape.global_batch * shape.seq_len) % dp == 0 else 1
-        cfg = dataclasses.replace(
-            cfg, moe=dataclasses.replace(cfg.moe, dispatch_groups=groups))
+        # GSPMD cannot partition the dropless grouped matmul: capacity
+        # dispatch, one group per DP shard
+        cfg = dataclasses.replace(cfg, moe=moe.for_gspmd(
+            cfg.moe, n_chips // mesh.shape["model"],
+            shape.global_batch * shape.seq_len))
     t0 = time.time()
 
     key_s = jax.ShapeDtypeStruct((2,), jnp.uint32)

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import dataclasses
 import os
 import sys
 import time
@@ -95,6 +96,7 @@ def main(argv=None):
     from repro.distributed import sharding
     from repro.ft import abft, elastic, inject, watchdog
     from repro.launch.mesh import make_host_mesh
+    from repro.models import moe
     from repro.optim import adamw, powersgd, schedule
     from repro.train import train_step as ts
 
@@ -102,6 +104,11 @@ def main(argv=None):
     mesh = make_host_mesh(model=args.model_axis,
                           devices=(jax.devices()[:args.devices]
                                    if args.devices else None))
+    if cfg.moe is not None and mesh.size > 1:
+        # GSPMD cannot partition the dropless grouped matmul: capacity
+        # dispatch, one group per data shard
+        cfg = dataclasses.replace(cfg, moe=moe.for_gspmd(
+            cfg.moe, mesh.shape["data"], args.global_batch * args.seq_len))
     host_index = jax.process_index()
     host_count = jax.process_count()
 

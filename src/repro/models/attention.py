@@ -247,84 +247,110 @@ def mla_init(key, d_model: int, n_heads: int, *, q_lora: int, kv_lora: int,
     }
 
 
-def _mla_q(params, x, positions, *, n_heads, nope_dim, rope_dim, rope_theta):
+def _mla_q(params, x, positions, *, n_heads, nope_dim, rope_dim, inv_freq, eps):
     b, s, _ = x.shape
-    cq = layers.rmsnorm(params["q_norm"], layers.dense(params["wdq"], x))
+    cq = layers.rmsnorm(params["q_norm"], layers.dense(params["wdq"], x), eps)
     q = layers.dense(params["wuq"], cq).reshape(b, s, n_heads, nope_dim + rope_dim)
     q_nope, q_pe = q[..., :nope_dim], q[..., nope_dim:]
-    q_pe = layers.apply_rope(q_pe, positions, theta=rope_theta)
+    q_pe = layers.apply_rope(q_pe, positions, inv_freq=inv_freq)
     return q_nope, q_pe
 
 
-def _mla_latent(params, x, positions, *, rope_theta):
-    c = layers.rmsnorm(params["kv_norm"], layers.dense(params["wdkv"], x))
+def _mla_latent(params, x, positions, *, inv_freq, eps):
+    c = layers.rmsnorm(params["kv_norm"], layers.dense(params["wdkv"], x), eps)
     k_pe = layers.dense(params["wkr"], x)[:, :, None, :]      # (b,s,1,rope)
-    k_pe = layers.apply_rope(k_pe, positions, theta=rope_theta)
+    k_pe = layers.apply_rope(k_pe, positions, inv_freq=inv_freq)
     return c, k_pe
 
 
+def _mla_rope(nope_dim, rope_dim, rope_theta, yarn):
+    """(inverse frequencies of the rotated rope_dim, softmax scale): plain
+    RoPE at ``rope_theta`` and ``(nope + rope)^-0.5``, or YaRN's ramped
+    frequencies and that scale times its ``mscale^2``."""
+    scale = (nope_dim + rope_dim) ** -0.5
+    if yarn is None:
+        return layers.rope_freqs(rope_dim, 1.0, rope_theta)[0], scale
+    return yarn.inv_freq(rope_dim, rope_theta), scale * yarn.softmax_factor()
+
+
+@layers.scoped("attn")
 def mla_fwd(params, x, *, n_heads, nope_dim, rope_dim, v_dim,
-            rope_theta=10000.0, causal=True, q_chunk=1024, kv_chunk=1024,
-            positions=None):
-    """Full-sequence MLA. Returns (out, (c_latent, k_pe)) -- the latent cache."""
+            rope_theta=10000.0, yarn=None, norm_eps=1e-5, causal=True,
+            q_chunk=1024, kv_chunk=1024, positions=None):
+    """Full-sequence MLA. Returns (out, (c_latent, k_pe)) -- the latent cache.
+
+    ``yarn`` (``layers.YaRN``): DeepSeek-V3's rope scaling, in the rotated
+    frequencies and the softmax scale. ``norm_eps``: the q and kv latent
+    RMSNorms'. Profile scope ``attn``: the projections and ``attn_core``.
+    """
     b, s, _ = x.shape
     if positions is None:
         positions = jnp.arange(s)[None, :]
+    inv_freq, scale = _mla_rope(nope_dim, rope_dim, rope_theta, yarn)
     q_nope, q_pe = _mla_q(params, x, positions, n_heads=n_heads,
                           nope_dim=nope_dim, rope_dim=rope_dim,
-                          rope_theta=rope_theta)
-    c, k_pe = _mla_latent(params, x, positions, rope_theta=rope_theta)
+                          inv_freq=inv_freq, eps=norm_eps)
+    c, k_pe = _mla_latent(params, x, positions, inv_freq=inv_freq, eps=norm_eps)
     kv = layers.dense(params["wukv"], c).reshape(b, s, n_heads, nope_dim + v_dim)
     k_nope, v = kv[..., :nope_dim], kv[..., nope_dim:]
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe, (*k_pe.shape[:2], n_heads, rope_dim))], -1)
     q = jnp.concatenate([q_nope, q_pe], -1)
-    scale = (nope_dim + rope_dim) ** -0.5
     ctx = chunked_attention(q, k, v, causal=causal, q_chunk=q_chunk,
                             kv_chunk=kv_chunk, softmax_scale=scale)
     out = layers.dense(params["wo"], ctx.reshape(b, s, n_heads * v_dim))
     return out, (c, k_pe[:, :, 0, :])
 
 
+@layers.scoped("attn")
 def mla_decode(params, x, cache_c, cache_kpe, pos, *, n_heads, nope_dim,
-               rope_dim, v_dim, rope_theta=10000.0, absorb: bool = True):
-    """One-token MLA decode over the latent cache.
+               rope_dim, v_dim, rope_theta=10000.0, yarn=None, norm_eps=1e-5,
+               absorb: bool = True):
+    """One-token MLA decode over the latent cache; ``yarn`` and
+    ``norm_eps`` as ``mla_fwd``'s.
 
     ``absorb=True`` (beyond-paper optimization, recorded in §Perf): fold
     W_uk into the query and W_uv into the output so attention runs directly
     in the 512-dim latent space -- O(S * kv_lora) per step instead of
     re-expanding the whole cache to per-head k/v (O(S * H * (nope+v))).
+    Profile scopes: ``attn``; the folds, projections of the query and the
+    output, under ``dense``; the latent-space scores, softmax and values
+    under ``attn_core``.
     """
     b = x.shape[0]
     kv_lora = cache_c.shape[-1]
     positions = jnp.full((b, 1), pos, jnp.int32)
+    inv_freq, scale = _mla_rope(nope_dim, rope_dim, rope_theta, yarn)
     q_nope, q_pe = _mla_q(params, x, positions, n_heads=n_heads,
                           nope_dim=nope_dim, rope_dim=rope_dim,
-                          rope_theta=rope_theta)
-    c_new, kpe_new = _mla_latent(params, x, positions, rope_theta=rope_theta)
+                          inv_freq=inv_freq, eps=norm_eps)
+    c_new, kpe_new = _mla_latent(params, x, positions, inv_freq=inv_freq,
+                                 eps=norm_eps)
     cache_c = lax.dynamic_update_slice_in_dim(cache_c, c_new, pos, axis=1)
     cache_kpe = lax.dynamic_update_slice_in_dim(cache_kpe, kpe_new[:, :, 0, :], pos, axis=1)
-    scale = (nope_dim + rope_dim) ** -0.5
     s_len = cache_c.shape[1]
     wukv = params["wukv"].reshape(kv_lora, n_heads, nope_dim + v_dim)
     wuk, wuv = wukv[..., :nope_dim], wukv[..., nope_dim:]
 
     if absorb:
         # q_c[b,h,l] = sum_d q_nope[b,h,d] * wuk[l,h,d]
-        # repro: allow-raw-param-matmul (absorbed decode: the 3-D per-head
-        # W_uk slice folds into a batch-1 f32 einsum -- no 2-D tsmm form,
-        # and per-step shapes never classify tall-skinny)
-        q_c = jnp.einsum("bhd,lhd->bhl", q_nope[:, 0].astype(jnp.float32),
-                         wuk.astype(jnp.float32))
-        s_nope = jnp.einsum("bhl,bsl->bhs", q_c, cache_c.astype(jnp.float32))
-        s_pe = jnp.einsum("bhd,bsd->bhs", q_pe[:, 0].astype(jnp.float32),
-                          cache_kpe.astype(jnp.float32))
-        scores = (s_nope + s_pe) * scale
-        mask = jnp.arange(s_len)[None, None, :] <= pos
-        scores = jnp.where(mask, scores, _NEG)
-        p = jax.nn.softmax(scores, axis=-1)
-        ctx_c = jnp.einsum("bhs,bsl->bhl", p, cache_c.astype(jnp.float32))
-        # repro: allow-raw-param-matmul (absorbed decode W_uv fold; see wuk)
-        ctx = jnp.einsum("bhl,lhd->bhd", ctx_c, wuv.astype(jnp.float32))
+        with jax.named_scope("dense"):
+            # repro: allow-raw-param-matmul (absorbed decode: the 3-D per-head
+            # W_uk slice folds into a batch-1 f32 einsum -- no 2-D tsmm form,
+            # and per-step shapes never classify tall-skinny)
+            q_c = jnp.einsum("bhd,lhd->bhl", q_nope[:, 0].astype(jnp.float32),
+                             wuk.astype(jnp.float32))
+        with jax.named_scope("attn_core"):
+            s_nope = jnp.einsum("bhl,bsl->bhs", q_c, cache_c.astype(jnp.float32))
+            s_pe = jnp.einsum("bhd,bsd->bhs", q_pe[:, 0].astype(jnp.float32),
+                              cache_kpe.astype(jnp.float32))
+            scores = (s_nope + s_pe) * scale
+            mask = jnp.arange(s_len)[None, None, :] <= pos
+            scores = jnp.where(mask, scores, _NEG)
+            p = jax.nn.softmax(scores, axis=-1)
+            ctx_c = jnp.einsum("bhs,bsl->bhl", p, cache_c.astype(jnp.float32))
+        with jax.named_scope("dense"):
+            # repro: allow-raw-param-matmul (absorbed decode W_uv fold; see wuk)
+            ctx = jnp.einsum("bhl,lhd->bhd", ctx_c, wuv.astype(jnp.float32))
     else:
         # repro: allow-raw-param-matmul (non-absorbed decode re-expands the
         # latent cache through the 3-D per-head W_ukv -- same exemption as

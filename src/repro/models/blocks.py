@@ -60,7 +60,8 @@ def _attn_kwargs(cfg):
 def _mla_kwargs(cfg):
     m = cfg.mla
     return dict(n_heads=cfg.n_heads, nope_dim=m.nope_dim, rope_dim=m.rope_dim,
-                v_dim=m.v_dim, rope_theta=cfg.rope_theta)
+                v_dim=m.v_dim, rope_theta=cfg.rope_theta, yarn=m.yarn,
+                norm_eps=cfg.norm_eps)
 
 
 # ---------------------------------------------------------------------------

@@ -9,11 +9,14 @@ the MXU without transposes.
 
 from __future__ import annotations
 
+import dataclasses
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
-
-import functools
 
 from repro.core import tsmm
 
@@ -148,14 +151,58 @@ def rope_freqs(head_dim: int, fraction: float, theta: float):
     return 1.0 / (theta ** (jnp.arange(0, rot, 2, jnp.float32) / rot)), rot
 
 
-def apply_rope(x, positions, *, theta: float = 10000.0, fraction: float = 1.0):
+@dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN context extension as DeepSeek-V3's published modeling code
+    applies it (``rope_scaling`` of its config.json, type "yarn").
+
+    Inverse frequencies are ramped between the plain ones (dimensions that
+    turn more than ``beta_fast`` times over ``original_max_pos`` tokens)
+    and the plain ones over ``factor`` (those that turn fewer than
+    ``beta_slow`` times). The softmax scale is multiplied by ``mscale**2``,
+    ``mscale = 0.1 * mscale_all_dim * ln(factor) + 1``; the config's
+    ``mscale`` equals ``mscale_all_dim`` (both 1), so cos and sin keep unit
+    amplitude.
+    """
+    factor: float
+    original_max_pos: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale_all_dim: float = 1.0
+
+    def inv_freq(self, dim: int, theta: float) -> np.ndarray:
+        def corr(rotations):
+            return (dim * math.log(self.original_max_pos / (rotations * 2 * math.pi))
+                    / (2 * math.log(theta)))
+
+        low = max(math.floor(corr(self.beta_fast)), 0)
+        high = min(math.ceil(corr(self.beta_slow)), dim - 1)
+        if low == high:
+            high += 0.001
+        ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low) / (high - low), 0, 1)
+        extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+        return (extra / self.factor * ramp + extra * (1 - ramp)).astype(np.float32)
+
+    def softmax_factor(self) -> float:
+        if self.factor <= 1:
+            return 1.0
+        return (0.1 * self.mscale_all_dim * math.log(self.factor) + 1.0) ** 2
+
+
+def apply_rope(x, positions, *, theta: float = 10000.0, fraction: float = 1.0,
+               inv_freq=None):
     """x: (..., S, H, D); positions: broadcastable to (..., S).
 
     ``fraction < 1`` rotates only the leading slice of D (ChatGLM-style
     partial / '2d' RoPE); the remainder passes through unrotated.
+    ``inv_freq`` (D/2,) replaces the plain frequencies of ``theta`` over the
+    whole of D (MLA's YaRN frequencies).
     """
     d = x.shape[-1]
-    inv_freq, rot = rope_freqs(d, fraction, theta)
+    if inv_freq is None:
+        inv_freq, rot = rope_freqs(d, fraction, theta)
+    else:
+        rot = d
     if rot == 0:
         return x
     ang = positions[..., None].astype(jnp.float32) * inv_freq  # (..., S, rot/2)

@@ -1,8 +1,10 @@
 """The profile scopes of the serving path (``jax.named_scope``): present in
-the compiled ``op_name``s of the rwkv6 and zamba2 smoke configs' prefill and
-decode steps, covering every matrix product, and changing no instruction."""
+the compiled ``op_name``s of the rwkv6, zamba2 and deepseek-v3 smoke
+configs' prefill and decode steps, covering every matrix product, and
+changing no instruction."""
 
 import contextlib
+import hashlib
 import re
 
 import jax
@@ -20,10 +22,24 @@ EXPECTED = {
     "zamba2-1.2b": {"serve.prefill", "serve.decode", "embed", "layers", "block",
                     "shared_block", "mamba", "ssd", "attn", "attn_core", "mlp",
                     "dense", "lora", "unembed"},
+    "deepseek-v3-671b": {"serve.prefill", "serve.decode", "embed", "layers",
+                         "block", "attn", "attn_core", "mlp", "moe", "router",
+                         "dispatch", "experts", "dense", "unembed"},
 }
-# Every matrix product lies under one of these: a projection or a sequence mixer.
-GEMM_SCOPES = {"dense", "unembed", "wkv", "ssd", "attn_core"}
+# Every matrix product lies under one of these: a projection, a sequence
+# mixer, the held experts' grouped matmuls or the expert layer's combine.
+GEMM_SCOPES = {"dense", "unembed", "wkv", "ssd", "attn_core", "experts", "dispatch"}
 STEPS = [(arch, step) for arch in EXPECTED for step in ("prefill", "decode")]
+# sha256 of ``_computations`` of the rwkv6 and zamba2 smoke entries, recorded
+# on the tree before MLA gained YaRN and the expert layer its held share and
+# dropless dispatch: neither change may reach these models. A change meant
+# to alter their programs records the new digests.
+BEFORE = {
+    ("rwkv6-1.6b", "prefill"): "5ad617666453c990805c0c5f14384fda911001ef73c3f3f391fb7bf351c6ea46",
+    ("rwkv6-1.6b", "decode"): "00b1eaaa512c86fbd58e0da17bed9b8b95d137342e3139560a907427e9ccb752",
+    ("zamba2-1.2b", "prefill"): "9f04f46dea9e2945d334f4d3ab31551e4e655abf1e1578aaf21bfe5db5816646",
+    ("zamba2-1.2b", "decode"): "be182e0d57c50de86e574350cc42ef9a72a1e6bcb261d8b3cbedc9ffe77cf349",
+}
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = ")
 _META = re.compile(r", metadata=\{[^}]*\}")
@@ -78,6 +94,26 @@ def test_each_scope_appears(compiled, arch):
             seen |= _components(line)
     assert EXPECTED[arch] <= seen, EXPECTED[arch] - seen
     assert any(c.startswith("tsmm.") for c in seen)
+
+
+def test_mla_and_expert_scopes_in_prefill(compiled):
+    """MLA's ``attn``/``attn_core`` and the expert layer's ``moe`` with
+    ``router``, ``dispatch`` and ``experts`` beneath it name the compiled
+    prefill's ops, in that nesting."""
+    paths = {"/".join(c for c in m.group(1).split("/")[:-1] if "(" not in c)
+             for ln in _instructions(compiled("deepseek-v3-671b", "prefill")).values()
+             if (m := re.search(r'op_name="([^";]*)', ln))}
+    for want in ("block/attn/", "attn/attn_core", "block/moe/router", "moe/dispatch",
+                 "moe/experts", "moe/dense"):
+        assert any(want in p + "/" for p in paths), want
+
+
+@pytest.mark.parametrize("arch,step", sorted(BEFORE))
+def test_entries_compile_as_before(compiled, arch, step):
+    """The rwkv6 and zamba2 entries hold the instructions recorded before
+    (metadata stripped, fused instructions named by place)."""
+    lines = [f"{c}\t{ln}" for c, ln in _computations(compiled(arch, step))]
+    assert hashlib.sha256("\n".join(lines).encode()).hexdigest() == BEFORE[arch, step]
 
 
 @pytest.mark.parametrize("arch,step", STEPS)

@@ -203,7 +203,7 @@ def test_rwkv6_channel_mix_shift():
 # MoE
 # ---------------------------------------------------------------------------
 
-CFG_E = MoEConfig(n_experts=4, top_k=2, d_ff_expert=32, capacity_factor=2.0)
+CFG_E = MoEConfig(n_experts=4, top_k=2, d_ff_expert=32)
 
 
 def _moe_params(key, d=16, cfg=CFG_E):
@@ -211,17 +211,19 @@ def _moe_params(key, d=16, cfg=CFG_E):
 
 
 def dense_moe_oracle(params, x, cfg):
-    """All-experts dense evaluation with the same router weights."""
+    """Held experts evaluated densely for every token, weighted by the same
+    router's combine weights (zero where an expert is not chosen)."""
     b, s, d = x.shape
     xt = x.reshape(-1, d)
     w, idx, _ = moe.route(params, xt, cfg)
+    e0, eh = cfg.held
     ew = params["experts"]
     g = jnp.einsum("td,edf->tef", xt, ew["w_gate"])
     u = jnp.einsum("td,edf->tef", xt, ew["w_up"])
     y = jnp.einsum("tef,efd->ted", jax.nn.silu(g) * u, ew["w_down"])
-    onehot = jax.nn.one_hot(idx, cfg.n_experts)          # (t,k,e)
+    onehot = jax.nn.one_hot(idx - e0, eh)                # (t,k,eh); not held: 0
     combine = jnp.einsum("tk,tke->te", w, onehot)
-    out = jnp.einsum("te,ted->td", combine, y) * cfg.routed_scale
+    out = jnp.einsum("te,ted->td", combine, y)
     if cfg.n_shared:
         from repro.models import layers
         out = out + layers.swiglu(params["shared"], xt)
@@ -230,25 +232,125 @@ def dense_moe_oracle(params, x, cfg):
 
 @pytest.mark.parametrize("router", ["softmax", "sigmoid"])
 def test_moe_matches_dense_oracle(router):
-    cfg = MoEConfig(n_experts=4, top_k=2, d_ff_expert=32, capacity_factor=8.0,
+    cfg = MoEConfig(n_experts=4, top_k=2, d_ff_expert=32,
                     router=router, n_shared=1 if router == "sigmoid" else 0,
                     d_ff_shared=32, routed_scale=2.5 if router == "sigmoid" else 1.0)
     p = _moe_params(jax.random.PRNGKey(0), cfg=cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 16))
-    got, metrics = moe.moe_fwd(p, x, cfg)
+    got, _ = moe.moe_fwd(p, x, cfg)
     want = dense_moe_oracle(p, x, cfg)
-    # capacity_factor=8 => nothing dropped => exact match
-    assert float(metrics["moe_dropped_frac"]) < 1e-6
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
 
+def test_moe_dropless_under_full_skew(monkeypatch):
+    """Every token routed to one held expert: the buffer of held pairs takes
+    them all (the large buffer, since the small one holds 4x an even share)
+    and the layer matches the dense oracle. Row tiles of 8 make the small
+    buffer smaller than the large one at this size."""
+    monkeypatch.setattr(moe, "TM", 8)
+    cfg = MoEConfig(n_experts=16, top_k=2, d_ff_expert=16, router="sigmoid",
+                    n_shared=1, d_ff_shared=16, routed_scale=2.5, n_group=4,
+                    topk_group=2, first_held=4, n_held=4)
+    p = _moe_params(jax.random.PRNGKey(2), cfg=cfg)
+    p["router_bias"] = p["router_bias"].at[5].set(10.0)     # held expert 1 of 4
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, 64, 16))
+    _, idx, _ = moe.route(p, x.reshape(-1, 16), cfg)
+    assert (np.asarray(idx) == 5).any(-1).all()
+    got, _ = moe.moe_fwd(p, x, cfg)
+    np.testing.assert_allclose(got, dense_moe_oracle(p, x, cfg), rtol=1e-5, atol=1e-5)
+
+
+def test_moe_held_shares_sum_to_whole_layer(monkeypatch):
+    """Expert parallelism over 4 ranks: each rank holds 4 of 16 experts and
+    routes over all 16; the ranks' outputs, with the shared expert counted
+    once, add up to the layer that holds every expert."""
+    monkeypatch.setattr(moe, "TM", 8)
+    whole = MoEConfig(n_experts=16, top_k=4, d_ff_expert=16, router="sigmoid",
+                      n_shared=1, d_ff_shared=16, routed_scale=2.5, n_group=4,
+                      topk_group=2)
+    p = _moe_params(jax.random.PRNGKey(4), cfg=whole)
+    p["router_bias"] = 0.05 * jax.random.normal(jax.random.PRNGKey(5), (16,))
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 24, 16))
+    want, _ = moe.moe_fwd(p, x, whole)
+    from repro.models import layers
+    shared = layers.swiglu(p["shared"], x)
+    total = shared
+    for rank in range(4):
+        cfg = dataclasses.replace(whole, first_held=4 * rank, n_held=4)
+        pr = {**p, "experts": jax.tree.map(lambda a: a[4 * rank:4 * rank + 4],
+                                           p["experts"])}
+        got, _ = moe.moe_fwd(pr, x, cfg)
+        total = total + (got - shared)
+    np.testing.assert_allclose(total, want, rtol=1e-5, atol=1e-5)
+
+
 def test_moe_capacity_drops_excess():
+    """The GSPMD paths' capacity dispatch drops pairs past capacity."""
     cfg = MoEConfig(n_experts=4, top_k=2, d_ff_expert=16, capacity_factor=0.25)
     p = _moe_params(jax.random.PRNGKey(2), cfg=cfg)
     x = jax.random.normal(jax.random.PRNGKey(3), (1, 64, 16))
     out, metrics = moe.moe_fwd(p, x, cfg)
     assert float(metrics["moe_dropped_frac"]) > 0.0
     assert np.isfinite(np.asarray(out)).all()
+
+
+@pytest.mark.parametrize("groups", [1, 4])
+def test_moe_capacity_with_room_matches_dense_oracle(groups):
+    """With room for every pair, capacity dispatch in any number of groups
+    is the dropless layer: the same noaux_tc routing, nothing dropped."""
+    cfg = moe.for_gspmd(MoEConfig(n_experts=8, top_k=2, d_ff_expert=16,
+                                  router="sigmoid", n_shared=1, d_ff_shared=16,
+                                  routed_scale=2.5, n_group=4, topk_group=2),
+                        groups, 32)
+    cfg = dataclasses.replace(cfg, capacity_factor=8.0)
+    assert cfg.dispatch_groups == groups
+    p = _moe_params(jax.random.PRNGKey(10), cfg=cfg)
+    x = jax.random.normal(jax.random.PRNGKey(11), (2, 16, 16))
+    got, metrics = moe.moe_fwd(p, x, cfg)
+    assert float(metrics["moe_dropped_frac"]) < 1e-6
+    np.testing.assert_allclose(got, dense_moe_oracle(p, x, cfg), rtol=2e-4, atol=2e-4)
+
+
+def test_moe_held_count_defaults_to_every_expert():
+    cfg = MoEConfig(n_experts=8, top_k=2, d_ff_expert=16)
+    assert cfg.n_held == 8 and cfg.held == (0, 8)
+    assert MoEConfig(8, 2, 16, first_held=4, n_held=4).held == (4, 4)
+
+
+def _noaux_tc_loop(scores, bias, cfg):
+    """DeepSeek-V3's noaux_tc selection one token at a time, in numpy."""
+    e = scores.shape[-1]
+    per = e // cfg.n_group
+    ids, weights = [], []
+    for s_t in scores:
+        biased = s_t + bias
+        groups = sorted(range(cfg.n_group), key=lambda g: -np.sort(
+            biased[g * per:(g + 1) * per])[-2:].sum())[:cfg.topk_group]
+        cand = [i for g in groups for i in range(g * per, (g + 1) * per)]
+        chosen = sorted(cand, key=lambda i: -biased[i])[:cfg.top_k]
+        w = s_t[chosen] / s_t[chosen].sum() * cfg.routed_scale
+        ids.append(chosen)
+        weights.append(w)
+    return np.array(ids), np.array(weights)
+
+
+def test_noaux_tc_selection_matches_loop_oracle():
+    """Group-limited selection picks the oracle's experts; the bias moves the
+    choice but not the combine weights, which are the unbiased scores
+    renormalised and scaled."""
+    cfg = MoEConfig(n_experts=32, top_k=4, d_ff_expert=8, router="sigmoid",
+                    routed_scale=2.5, n_group=8, topk_group=3)
+    p = _moe_params(jax.random.PRNGKey(7), cfg=cfg)
+    p["router_bias"] = 0.1 * jax.random.normal(jax.random.PRNGKey(8), (32,))
+    xt = jax.random.normal(jax.random.PRNGKey(9), (64, 16))
+    w, idx, _ = moe.route(p, xt, cfg)
+    scores = np.asarray(jax.nn.sigmoid(xt @ p["router_w"]), np.float64)
+    want_idx, want_w = _noaux_tc_loop(scores, np.asarray(p["router_bias"], np.float64), cfg)
+    np.testing.assert_array_equal(np.asarray(idx), want_idx)
+    np.testing.assert_allclose(np.asarray(w), want_w, rtol=1e-5)
+    _, idx0, _ = moe.route({**p, "router_bias": jnp.zeros(32)}, xt, cfg)
+    moved = [set(a) != set(b) for a, b in zip(np.asarray(idx), np.asarray(idx0))]
+    assert 0 < np.mean(moved) < 1
 
 
 def test_moe_weights_sum_to_one():
@@ -272,12 +374,11 @@ def test_router_bias_pushes_balance():
 @settings(max_examples=10, deadline=None)
 @given(seed=st.integers(0, 999), t=st.integers(8, 48))
 def test_moe_token_conservation(seed, t):
-    """With ample capacity every token receives exactly its top-k mixture:
-    output is linear in the combine weights which sum to 1 -- check the
-    combine path by verifying no token's output is zeroed."""
-    cfg = MoEConfig(n_experts=4, top_k=2, d_ff_expert=8, capacity_factor=8.0)
+    """Every token receives its top-k mixture: the output is linear in the
+    combine weights, which sum to 1 -- check the combine path by verifying
+    no token's output is zeroed."""
+    cfg = MoEConfig(n_experts=4, top_k=2, d_ff_expert=8)
     p = _moe_params(jax.random.PRNGKey(seed), cfg=cfg)
     x = jax.random.normal(jax.random.PRNGKey(seed + 1), (1, t, 16))
-    out, metrics = moe.moe_fwd(p, x, cfg)
-    assert float(metrics["moe_dropped_frac"]) < 1e-6
+    out, _ = moe.moe_fwd(p, x, cfg)
     assert (np.abs(np.asarray(out)).sum(-1) > 0).all()
