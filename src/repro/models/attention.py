@@ -1,24 +1,44 @@
-"""Attention: chunked online-softmax (flash-style in pure JAX), GQA, SWA,
-MLA (DeepSeek latent attention), cross-attention, and decode paths.
+"""Attention: causal prefill in one fused kernel, chunked online-softmax
+(flash-style in pure JAX) for the rest, GQA, SWA, MLA (DeepSeek latent
+attention), cross-attention, and decode paths.
 
-Why chunked: materializing (B, H, S, S) scores at S=32k would need ~17 GB
-per device; the two-level chunk scan keeps the live score tile at
-(q_chunk x kv_chunk) with exact online-softmax accumulation (f32 stats).
-
-Causality at chunk granularity: fully-masked chunk pairs are still
-computed and zeroed (static grid). This ~2x waste on causal prefill is the
-*paper-faithful baseline*; the §Perf hillclimb evaluates block-skipping.
+Which path ``chunked_attention`` runs, read from its call:
+- Causal self-attention from position 0 over every key (no window, no
+  padded cache, Sq == Skv, a length that tiles into lane-aligned blocks of
+  at most ``KERNEL_BLOCK``, values a multiple of 128 wide), with no
+  multi-chip GSPMD mesh in context: one Pallas kernel
+  (``kernels/attention.py``). Each step keeps a (block x block) score tile,
+  its f32 softmax statistics and the output accumulator in VMEM, and steps
+  wholly above the diagonal are never visited; the f32 score tiles the
+  scan writes to HBM go away (PERF.md). Its VJP is two kernels of the same
+  kind (dq; dk and dv), so one-chip training runs it too.
+- Everything else -- cross-attention, non-causal encoders, sliding
+  windows, padded caches, continuation offsets, lengths that do not tile,
+  GSPMD meshes (a Pallas call has no partitioning rule) -- runs the
+  two-level chunk scan. It keeps the live f32 score tile at
+  (q_chunk x kv_chunk) with exact online-softmax accumulation, so
+  (B, H, S, S) scores are never materialized (~17 GB per device at S=32k).
+  Causality there is at chunk granularity: fully-masked chunk pairs are
+  still computed and zeroed (static grid).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.kernels import attention as attention_kernel
+from repro.kernels import compat
 from repro.models import layers
 
 _NEG = -1e30
+# Most rows of q and of k/v per step of the fused causal kernel: a sequence
+# of up to this many tokens is one block, a longer one is cut into blocks of
+# this many (the chip's timing at S=1024: PERF.md).
+KERNEL_BLOCK = 1024
 
 
 # ---------------------------------------------------------------------------
@@ -35,18 +55,36 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int | None = None
     ``q_offset``: global position of q[0] (prefill continuation / decode).
     ``kv_valid_len``: mask out cache slots >= this (scalar or (B,)).
     Supports Dk != Dv (MLA attends with 192-dim keys, 128-dim values).
+    Causal self-attention from a static position 0 over every key, at a
+    length that tiles into the kernel's blocks, with values a multiple of
+    128 wide and outside a multi-chip mesh, runs the fused kernel
+    (``_causal_kernel``); everything else the chunk scan, in
+    (q_chunk x kv_chunk) tiles. Both take bf16 operands into f32 scores and
+    statistics and feed the probabilities to the MXU in one bf16 pass, as
+    the chip runs an f32 product at default precision.
     Profile scope ``attn_core``: scores, softmax and values.
     """
+    scale = softmax_scale if softmax_scale is not None else q.shape[-1] ** -0.5
+    if _takes_kernel(q, k, v, causal=causal, window=window, q_offset=q_offset,
+                     kv_valid_len=kv_valid_len):
+        return _causal_kernel(q, k, v, scale)
+    return _chunk_scan(q, k, v, causal=causal, window=window, q_offset=q_offset,
+                       kv_valid_len=kv_valid_len, q_chunk=q_chunk,
+                       kv_chunk=kv_chunk, scale=scale)
+
+
+def _chunk_scan(q, k, v, *, causal, window, q_offset, kv_valid_len, q_chunk,
+                kv_chunk, scale):
+    """``chunked_attention`` as a two-level scan over (q chunk, kv chunk)
+    pairs, every pair computed."""
     b, sq, h, dk = q.shape
     _, skv, hk, _ = k.shape
     dv = v.shape[-1]
     g = h // hk
-    scale = softmax_scale if softmax_scale is not None else dk ** -0.5
 
     # Pad sequences to chunk multiples rather than shrinking the chunk: a
     # divisor-shrink fallback degenerates to chunk=1 on prime lengths
-    # (vision_seq=1601 produced a 1601-step kv scan per cross-attn layer —
-    # caught by the roofline table, EXPERIMENTS.md §Perf).
+    # (vision_seq=1601 gave a 1601-step kv scan per cross-attn layer).
     if kv_valid_len is None:
         kv_valid = jnp.full((b,), skv, jnp.int32)
     else:
@@ -110,6 +148,65 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int | None = None
     _, outs = lax.scan(q_step, None, (jnp.arange(nq), jnp.moveaxis(qs, 1, 0)))
     out = jnp.moveaxis(outs, 0, 1).reshape(b, sq_pad, h, dv)[:, :sq]
     return out.astype(q.dtype)
+
+
+def _kernel_block(s: int) -> int:
+    """Rows of q and of k/v per kernel step for a sequence of s tokens."""
+    return min(s, KERNEL_BLOCK)
+
+
+def _takes_kernel(q, k, v, *, causal, window, q_offset, kv_valid_len) -> bool:
+    """Whether ``chunked_attention`` runs the fused kernel: causal
+    self-attention from position 0 over every key, at a length that tiles
+    into lane-aligned blocks and with values a multiple of the 128 lanes
+    wide, outside a multi-chip GSPMD mesh (a Pallas call has no partitioning
+    rule there; ``core/tsmm.py`` applies the same test)."""
+    s = q.shape[1]
+    block = _kernel_block(s)
+    mesh = compat.get_context_mesh()
+    return (causal and window is None and kv_valid_len is None
+            and isinstance(q_offset, int) and q_offset == 0
+            and k.shape[1] == s and s % block == 0 and block % 128 == 0
+            and v.shape[-1] % 128 == 0
+            and (mesh is None or mesh.size == 1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _causal_kernel(q, k, v, scale):
+    """Causal attention of q (B, S, H, Dk) over k (B, S, Hk, Dk) and
+    v (B, S, Hk, Dv) in one Pallas kernel (``kernels/attention.py``), which
+    reads them head-major and writes the output token-major. Its VJP is the
+    kernel's own backward, from the forward's row log-sum-exp."""
+    b, s, h, _ = q.shape
+    out = attention_kernel.causal_attention(
+        *_head_major(q, k, v), scale=scale, block=_kernel_block(s),
+        interpret=compat.auto_interpret(None))
+    return out.reshape(b, s, h, v.shape[-1])
+
+
+def _head_major(*xs):
+    return tuple(jnp.swapaxes(x, 1, 2) for x in xs)
+
+
+def _causal_kernel_fwd(q, k, v, scale):
+    b, s, h, _ = q.shape
+    qkv = _head_major(q, k, v)
+    out, lse = attention_kernel.causal_attention(
+        *qkv, scale=scale, block=_kernel_block(s),
+        interpret=compat.auto_interpret(None), with_lse=True)
+    return out.reshape(b, s, h, v.shape[-1]), (qkv, out, lse)
+
+
+def _causal_kernel_bwd(scale, res, g):
+    qkv, out, lse = res
+    grads = attention_kernel.causal_attention_vjp(
+        *qkv, out, lse, g.reshape(out.shape), scale=scale,
+        block=_kernel_block(out.shape[1]),
+        interpret=compat.auto_interpret(None))
+    return _head_major(*grads)
+
+
+_causal_kernel.defvjp(_causal_kernel_fwd, _causal_kernel_bwd)
 
 
 @layers.scoped("attn_core")

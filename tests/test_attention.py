@@ -1,5 +1,8 @@
 """Attention substrate tests: chunked online-softmax vs naive oracle,
-GQA grouping, SWA windows, MLA, decode equivalence, ring caches."""
+GQA grouping, SWA windows, MLA, decode equivalence, ring caches, and the
+fused causal kernel against the chunk scan and its path choice."""
+
+import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -7,6 +10,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.kernels import compat
 from repro.models import attention
 
 TOL = dict(rtol=2e-4, atol=2e-5)
@@ -161,3 +165,157 @@ def test_property_causality(sq, h, seed):
     v2v = v.at[:, t:].add(1.0)
     out2 = attention.chunked_attention(q, k2v, v2v, q_chunk=8, kv_chunk=8)
     np.testing.assert_allclose(out1[:, :t], out2[:, :t], rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The fused causal kernel (interpret mode on the CPU) against the chunk scan
+# ---------------------------------------------------------------------------
+
+BF16_TOL = dict(rtol=2e-2, atol=2e-2)      # the kernels' bf16 tolerance
+S_KERNEL = 2 * attention.KERNEL_BLOCK      # two blocks: (q0, kv1) fully masked
+
+
+def _spy_kernel(monkeypatch):
+    """Record each call of the fused kernel (at trace time)."""
+    calls = []
+    real = attention._causal_kernel
+
+    def spy(q, *args, **kwargs):
+        calls.append(q.shape)
+        return real(q, *args, **kwargs)
+    monkeypatch.setattr(attention, "_causal_kernel", spy)
+    return calls
+
+
+def _qkv_dims(key, b, s, h, hk, dk, dv, dtype=jnp.float32):
+    q, k, _ = _qkv(key, b, s, s, h, hk, dk, dtype)
+    v = jax.random.normal(jax.random.fold_in(key, 1), (b, s, hk, dv), dtype)
+    return q, k, v
+
+
+def _scan(q, k, v, scale=None):
+    return attention._chunk_scan(
+        q, k, v, causal=True, window=None, q_offset=0, kv_valid_len=None,
+        q_chunk=attention.KERNEL_BLOCK, kv_chunk=attention.KERNEL_BLOCK,
+        scale=scale if scale is not None else q.shape[-1] ** -0.5)
+
+
+@pytest.mark.parametrize("b,h,hk,dk,dv", [
+    (1, 4, 4, 192, 128),    # MLA's widths (Dk != Dv), heads scaled down
+    (2, 4, 2, 128, 128),    # GQA, g = 2
+    (1, 2, 2, 128, 128),    # one kv head per q head (zamba2's attention)
+    (1, 4, 1, 128, 128),    # one kv head for all
+], ids=["mla", "gqa_g2", "mha_g1", "mqa"])
+def test_causal_kernel_matches_scan(monkeypatch, b, h, hk, dk, dv):
+    calls = _spy_kernel(monkeypatch)
+    q, k, v = _qkv_dims(jax.random.PRNGKey(20), b, S_KERNEL, h, hk, dk, dv)
+    got = attention.chunked_attention(q, k, v, q_chunk=512, kv_chunk=512,
+                                      softmax_scale=0.13)
+    assert calls == [q.shape]
+    np.testing.assert_allclose(got, _scan(q, k, v, 0.13), **TOL)
+
+
+def test_causal_kernel_bf16_matches_scan(monkeypatch):
+    calls = _spy_kernel(monkeypatch)
+    q, k, v = _qkv_dims(jax.random.PRNGKey(21), 1, S_KERNEL, 4, 4, 192, 128,
+                        jnp.bfloat16)
+    got = attention.chunked_attention(q, k, v)
+    assert calls and got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(got.astype(jnp.float32),
+                               _scan(q, k, v).astype(jnp.float32), **BF16_TOL)
+
+
+def test_causal_kernel_gradients_match_scan(monkeypatch):
+    """The kernel's own VJP (one-chip training) against the scan's."""
+    calls = _spy_kernel(monkeypatch)
+    q, k, v = _qkv_dims(jax.random.PRNGKey(22), 1, S_KERNEL, 4, 2, 64, 128)
+
+    def loss(attend):
+        return lambda q, k, v: (attend(q, k, v) ** 2).sum()
+    got = jax.grad(loss(attention.chunked_attention), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(_scan), argnums=(0, 1, 2))(q, k, v)
+    assert calls
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4 * float(jnp.abs(w).max()))
+
+
+@pytest.mark.parametrize("h,hk,dk,dtype", [
+    (4, 4, 192, jnp.float32),     # MLA's widths (Dk != Dv)
+    (4, 1, 128, jnp.float32),     # one kv head for all: dk, dv summed over 4
+    (2, 2, 192, jnp.bfloat16),    # bf16 operands, f32 statistics
+], ids=["mla", "mqa", "mla_bf16"])
+def test_causal_kernel_vjp_runs_the_backward_kernels(monkeypatch, h, hk, dk,
+                                                     dtype):
+    """Gradients through the kernel come from its own backward kernels
+    (``causal_attention_vjp``), not a recomputation by the scan, and agree
+    with the scan's."""
+    from repro.kernels import attention as attention_kernel
+    vjps = []
+    real = attention_kernel.causal_attention_vjp
+
+    def spy(*args, **kwargs):
+        vjps.append(args[0].shape)
+        return real(*args, **kwargs)
+    monkeypatch.setattr(attention_kernel, "causal_attention_vjp", spy)
+    scans = []
+    real_scan = attention._chunk_scan
+
+    def scan_spy(*args, **kwargs):
+        scans.append(args[0].shape)
+        return real_scan(*args, **kwargs)
+    q, k, v = _qkv_dims(jax.random.PRNGKey(24), 1, S_KERNEL, h, hk, dk, 128,
+                        dtype)
+    w = jax.random.normal(jax.random.PRNGKey(25), (*q.shape[:3], 128))
+
+    def loss(attend):
+        return lambda q, k, v: (attend(q, k, v).astype(jnp.float32) * w).sum()
+    got = jax.grad(loss(attention.chunked_attention), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(_scan), argnums=(0, 1, 2))(q, k, v)
+    monkeypatch.setattr(attention, "_chunk_scan", scan_spy)
+    jax.grad(loss(attention.chunked_attention), argnums=(0, 1, 2))(q, k, v)
+    assert vjps == [(1, h, S_KERNEL, dk)] * 2 and not scans
+    tol = TOL if dtype == jnp.float32 else BF16_TOL
+    for g, wnt in zip(got, want):
+        assert g.dtype == dtype
+        g, wnt = g.astype(jnp.float32), wnt.astype(jnp.float32)
+        np.testing.assert_allclose(g, wnt, rtol=tol["rtol"],
+                                   atol=tol["rtol"] * float(jnp.abs(wnt).max()))
+
+
+@pytest.mark.parametrize("case,kernel", [
+    ("causal_from_0", True),
+    ("one_block", True),
+    ("one_device_mesh", True),
+    ("window", False),
+    ("kv_valid_len", False),
+    ("non_causal", False),
+    ("q_offset", False),
+    ("traced_q_offset", False),
+    ("cross_lengths", False),
+    ("untiled_length", False),
+    ("narrow_values", False),
+    ("multi_device_mesh", False),
+])
+def test_attention_path_choice(monkeypatch, case, kernel):
+    """Only causal self-attention from a static 0 over every key, at a
+    tiling length, with lane-wide values and off a multi-chip mesh, takes
+    the kernel."""
+    from jax.sharding import Mesh
+    calls = _spy_kernel(monkeypatch)
+    s = {"untiled_length": 1000, "one_block": 256}.get(case, S_KERNEL)
+    dv = 64 if case == "narrow_values" else 128
+    q, k, v = _qkv_dims(jax.random.PRNGKey(23), 1, s, 2, 2, 128, dv)
+    kw = {"window": dict(window=256), "kv_valid_len": dict(kv_valid_len=900),
+          "non_causal": dict(causal=False), "q_offset": dict(q_offset=512),
+          "traced_q_offset": dict(q_offset=jnp.int32(0))}.get(case, {})
+    if case == "cross_lengths":
+        k, v = k[:, :attention.KERNEL_BLOCK], v[:, :attention.KERNEL_BLOCK]
+    mesh = {"one_device_mesh": lambda: jax.set_mesh(
+                Mesh(np.array(jax.devices()[:1]), ("data",))),
+            "multi_device_mesh": lambda: jax.sharding.use_abstract_mesh(
+                compat.abstract_mesh((2,), ("data",)))}.get(case)
+    with (mesh() if mesh else contextlib.nullcontext()):
+        out = jax.eval_shape(
+            lambda q, k, v: attention.chunked_attention(q, k, v, **kw), q, k, v)
+    assert out.shape == (*q.shape[:3], dv)
+    assert bool(calls) == kernel

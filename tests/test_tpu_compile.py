@@ -1,4 +1,5 @@
-"""Real-width compiles of the TSM2X kernels for a TPU v5e, with no chip.
+"""Real-width compiles of the TSM2X kernels, and of prefill's fused causal
+attention, for a TPU v5e, with no chip.
 
 Every other kernel test runs in Pallas interpret mode, which cannot see
 what Mosaic refuses: blocks off the (8, 128) tiling, or more VMEM than the
@@ -105,3 +106,42 @@ def test_split_tsm2r_reaches_pallas_reduce(one_chip):
     kinds = [lm.kind for e in events for lm in e.launches]
     assert kinds == ["tsm2r", "reduce"]
     assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("b,h,dk,dv", [(4, 128, 192, 128), (4, 32, 128, 128)],
+                         ids=["mla", "zamba2"])
+def test_causal_attention_kernel_compiles_for_v5e(one_chip, monkeypatch,
+                                                  b, h, dk, dv):
+    """Prefill's fused causal attention at the benchmark's widths (1024
+    tokens): one Mosaic kernel, its op_name under ``attn/attn_core`` on the
+    instruction's own line (where the benchmark's scope reader looks); its
+    gradient compiles to the forward with its log-sum-exp and the two
+    backward kernels, and no chunk scan."""
+    import re
+
+    from repro.kernels import compat
+    from repro.models import attention
+    monkeypatch.setattr(compat, "auto_interpret", lambda requested=None: False)
+    s = 1024
+    q, k, v = (jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+               for d in (dk, dk, dv))
+
+    def fwd(q, k, v):
+        with jax.named_scope("attn"):
+            return attention.chunked_attention(q, k, v)
+    text = jax.jit(fwd).lower(q, k, v).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "causal_attention" in calls[0]
+    assert "/attn/attn_core/" in re.search(r'op_name="([^"]*)"', calls[0]).group(1)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, v).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    names = sorted(re.match(r"\s*%([a-z_]+)", c).group(1) for c in calls)
+    assert names == ["causal_attention", "causal_attention_dkv",
+                     "causal_attention_dq"]
+    assert " while(" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < V5E_HBM
